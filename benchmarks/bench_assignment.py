@@ -15,10 +15,7 @@ Choices are asserted bit-identical on every row (the speedup is free of
 semantic drift), and the acceptance row is N=32 / M=300 with a >= 5x target.
 A metrics-mode vs full-mode ``run_batch`` comparison quantifies what
 skipping ``ScheduledFlow``/``Assignment`` materialization buys end to end.
-
-The Pallas kernel path (``backend="pallas"``) is only timed on a real TPU
-backend — interpret-mode timings on CPU are meaningless; pass
-``--pallas`` / ``pallas=True`` to force it anyway.
+The Pallas kernel path is exercised on the chip by ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -56,7 +53,7 @@ def _time_stage(fn, repeats: int = 3):
 
 
 def main(grid=GRID, policies=("tau-aware", "rho-only", "random"),
-         pallas: bool = False, workers=None) -> list:
+         workers=None) -> list:
     trace = synth_fb_trace(526, seed=2026)
     rows = []
     print("== Assignment stage: flat-array front-end vs dataclass oracle ==")
@@ -95,26 +92,6 @@ def main(grid=GRID, policies=("tau-aware", "rho-only", "random"),
         print(f"acceptance (N=32, M=300, tau-aware): {target_speedup:.1f}x "
               f"vs >= {TARGET_SPEEDUP:.0f}x target -> {verdict}")
 
-    # Pallas kernel row: meaningful only where the kernel actually compiles.
-    import jax
-    if pallas or jax.default_backend() == "tpu":
-        from repro.core.engine import build_flow_table
-
-        N, M = grid[-1]
-        inst = sample_instance(trace, N=N, M=M, rates=[10, 20, 30], delta=8.0,
-                               seed=0)
-        pi = order_coflows(inst)
-        build_flow_table(inst, pi, "ours", backend="pallas")  # warm up jit
-        t_pl, table = _time_stage(
-            lambda: build_flow_table(inst, pi, "ours", backend="pallas"))
-        print(f"pallas backend (N={N}, M={M}, {table.n_flows} flows): "
-              f"{t_pl:.3f}s [{jax.default_backend()}]")
-        rows.append({"N": N, "M": M, "policy": "tau-aware-pallas",
-                     "flows": table.n_flows, "flat_s": t_pl})
-    else:
-        print("pallas backend: skipped (no TPU; interpret-mode timing is "
-              "meaningless — pass --pallas to force)")
-
     # End-to-end: what metrics-only materialization buys a sweep.
     N, M = grid[-1]
     inst = sample_instance(trace, N=N, M=M, rates=[10, 20, 30], delta=8.0,
@@ -132,5 +109,4 @@ def main(grid=GRID, policies=("tau-aware", "rho-only", "random"),
 
 
 if __name__ == "__main__":
-    import sys
-    main(pallas="--pallas" in sys.argv)
+    main()

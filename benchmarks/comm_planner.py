@@ -16,6 +16,7 @@ import textwrap
 
 SCRIPT = """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import json, dataclasses, jax
 from repro.launch.mesh import make_production_mesh
@@ -66,7 +67,8 @@ print("JSON::" + json.dumps(out))
 def main(archs=("phi3.5-moe-42b-a6.6b", "tinyllama-1.1b"),
          out_path="results/comm_planner.json") -> dict:
     code = SCRIPT % {"archs": repr(list(archs))}
-    env = dict(os.environ, PYTHONPATH="src")
+    # compile-only on stand-in devices: the child must never take the chip
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     res = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env=env)
     if res.returncode != 0:

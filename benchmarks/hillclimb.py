@@ -7,6 +7,7 @@ each hypothesis -> change -> measure cycle is grounded in the lowered IR.
 """
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"  # compile-only: never take the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import argparse
 import json

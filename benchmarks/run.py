@@ -72,6 +72,8 @@ def main(argv=None) -> int:
                          "section (inspect with `python -m repro.obs`)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     t0 = time.time()
     from benchmarks import (
         bench_assignment,

@@ -11,6 +11,7 @@ production path (512 devices) is benchmarks/comm_planner.py.
 """
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"  # stand-in devices: never take the chip
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse
@@ -20,6 +21,7 @@ import jax
 from repro.analysis.hlo import analyze_hlo
 from repro.comm import BlockMap, OCSFabric, plan_circuits, step_coflows
 from repro.distributed.sharding import TRAIN_RULES, batch_spec, plan_tree
+from repro.launch.mesh import make_mesh
 from repro.models.api import ModelConfig, build_model
 from repro.models.common import activation_sharding
 from repro.train.optimizer import OptimizerConfig, abstract_opt_state
@@ -31,7 +33,7 @@ def main():
     ap.add_argument("--experts", type=int, default=8)
     args = ap.parse_args()
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = ModelConfig(name="demo-moe", family="moe", n_layers=4, d_model=256,
                       n_heads=8, n_kv_heads=4, d_ff=512, vocab=1024,
                       n_experts=args.experts, top_k=2)

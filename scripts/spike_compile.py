@@ -1,5 +1,6 @@
 """Spike: can we lower+compile a big scanned transformer on 512 host devices in reasonable time?"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"  # compile-only: never take the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import time
 import jax
@@ -7,8 +8,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from functools import partial
 
+from repro.launch.mesh import make_production_mesh
+
 t0 = time.time()
-mesh = jax.make_mesh((2, 16, 16), ("pod", "data", "model"))
+mesh = make_production_mesh(multi_pod=True)
 print(f"mesh built {time.time()-t0:.1f}s ndev={len(jax.devices())}")
 
 L, D, F, H, V = 32, 4096, 14336, 32, 128256

@@ -106,7 +106,8 @@ def _start_method() -> str:
     modules spawn can't re-import), but forking a process whose JAX runtime
     is live risks deadlocking on XLA's internal threads — so once jax is
     imported, prefer spawn whenever the main module is re-importable.
-    Workers themselves only run numpy code either way.
+    Workers only ever run the numpy backend: ``run_batch`` keeps
+    ``backend="pallas"`` grids in the calling process, which holds the chip.
     """
     import multiprocessing as mp
     import sys
@@ -244,6 +245,9 @@ def run_batch(
     ``workers``: 0 or 1 for in-process serial execution; ``None`` picks a
     sensible default (serial for small grids, one process per CPU otherwise).
     Rows come back in deterministic grid order regardless of worker count.
+    ``backend="pallas"`` always runs serially in the calling process: a chip
+    belongs to one process, so worker processes could not reach it, and an
+    explicit ``workers > 1`` raises ``ValueError``.
     """
     from .engine import BACKENDS
 
@@ -290,6 +294,12 @@ def run_batch(
                         grid.append((idx, inst, rel, alg, sched, seed, check,
                                      backend, materialize))
 
+    if backend == "pallas":
+        if workers is not None and workers > 1:
+            raise ValueError(
+                f'backend="pallas" runs in the process that holds the chip; '
+                f"workers={workers} would start processes that cannot reach it")
+        workers = 0
     if workers is None:
         workers = 0 if len(grid) < 4 else min(os.cpu_count() or 1, len(grid), 16)
     if workers and workers > 1 and len(grid) > 1:

@@ -65,6 +65,7 @@ __all__ = [
     "run_fast_online",
     "run_fast_metrics",
     "cross_check",
+    "kernel_divergence",
     "cross_check_online",
     "cross_check_incremental",
 ]
@@ -1983,6 +1984,29 @@ def _oracle_assignment(inst: Instance, pi: np.ndarray, policy: str,
 _PALLAS_DIVERGENCE_CEILING = 0.03
 
 
+def kernel_divergence(
+    inst: Instance,
+    flows: tuple[np.ndarray, ...],
+    choices: Annotated[I8, "F"],
+) -> tuple[int, int]:
+    """``(diverged, allowed)`` of the Pallas kernel's choices.
+
+    ``diverged`` counts the flows (``coflow.extract_flows`` order) whose core
+    differs from ``kernels.ref.assign_ref`` evaluated at the kernel's
+    fp32-cast inputs; ``allowed`` is the precision-contract allowance
+    ``max(1, ceil(0.03 * F))``.
+    """
+    from repro.kernels.ref import assign_ref
+
+    _pos, _cid, fi, fj, sizes = flows
+    ref_c, _ = assign_ref(fi, fj, sizes.astype(np.float32),
+                          inst.rates.astype(np.float32),
+                          float(np.float32(inst.delta)), inst.N)
+    diverged = int((np.asarray(choices) != ref_c.astype(np.int64)).sum())
+    allowed = max(1, int(np.ceil(_PALLAS_DIVERGENCE_CEILING * fi.size)))
+    return diverged, allowed
+
+
 def _gate_choices(
     inst: Instance,
     pi: np.ndarray,
@@ -2009,14 +2033,7 @@ def _gate_choices(
     flows = extract_flows(inst, pi)
     if backend == "pallas" and policy == "tau-aware":
         choices = _pallas_choices(inst, flows)
-        from repro.kernels.ref import assign_ref
-
-        _pos, _cid, fi, fj, sizes = flows
-        ref_c, _ = assign_ref(fi, fj, sizes.astype(np.float32),
-                              inst.rates.astype(np.float32),
-                              float(np.float32(inst.delta)), inst.N)
-        diverged = int((choices != ref_c.astype(np.int64)).sum())
-        allowed = max(1, int(np.ceil(_PALLAS_DIVERGENCE_CEILING * choices.size)))
+        diverged, allowed = kernel_divergence(inst, flows, choices)
         if diverged > allowed:
             raise AssertionError(
                 f"pallas kernel/assign_ref diverge on {diverged}/{choices.size} "

@@ -35,22 +35,6 @@ __all__ = ["quantize_int8", "dequantize_int8", "init_error_state",
            "build_compressed_train_step"]
 
 
-def _shard_map(f, mesh, in_specs, out_specs, manual_axes):
-    """shard_map manual over only ``manual_axes``, across JAX versions.
-
-    New JAX exposes ``jax.shard_map(..., axis_names=...)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` where partial-manual is spelled
-    via ``auto`` (the complement of the manual axes).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(manual_axes),
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-    auto = frozenset(mesh.axis_names) - set(manual_axes)
-    return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False, auto=auto)
-
 BLOCK = 2048
 
 
@@ -81,43 +65,6 @@ def init_error_state(params_like: PyTree, n_pods: int) -> PyTree:
         lambda g: jnp.zeros((n_pods, *g.shape), jnp.float32), params_like)
 
 
-def _grads_fn_vmapped(model, params, batch, err, n_pods: int):
-    """Partial-manual-free emulation of the compressed pod hop.
-
-    jax 0.4.x's ``shard_map(..., auto=...)`` (manual over only the pod axis)
-    crashes XLA's sharding propagation on this program
-    (``Check failed: sharding.IsManualSubgroup()``), so on those versions we
-    compute per-pod gradients with ``vmap`` over an explicit leading pod dim
-    and express the compressed all-reduce as a sum over it. The arithmetic
-    is identical to the shard_map path (same quantize -> psum/n -> error
-    feedback); only the lowering differs — XLA is free to choose the wire
-    format, so this fallback validates numerics, not the int8 wire pattern.
-    """
-    batch_p = jax.tree_util.tree_map(
-        lambda x: x.reshape(n_pods, x.shape[0] // n_pods, *x.shape[1:]), batch)
-    losses, g_pods = jax.vmap(
-        lambda b: jax.value_and_grad(model.loss)(params, b))(batch_p)
-
-    def hop(gp, el):
-        work = gp.astype(jnp.float32) + el          # (n_pods, *shape)
-        q, scale = jax.vmap(quantize_int8)(work)
-        wire = q.astype(jnp.float32) * scale        # (n_pods, nB, BLOCK)
-        n = 1
-        for d in gp.shape[1:]:
-            n *= d
-        g_red = wire.sum(axis=0).reshape(-1)[:n] / n_pods
-        local = wire.reshape(n_pods, -1)[:, :n].reshape(work.shape)
-        new_el = work - local
-        return g_red.reshape(gp.shape[1:]).astype(gp.dtype), new_el
-
-    pairs = jax.tree_util.tree_map(hop, g_pods, err)
-    g_out = jax.tree_util.tree_map(
-        lambda p: p[0], pairs, is_leaf=lambda x: isinstance(x, tuple))
-    err_out = jax.tree_util.tree_map(
-        lambda p: p[1], pairs, is_leaf=lambda x: isinstance(x, tuple))
-    return losses.mean(), g_out, err_out
-
-
 def build_compressed_train_step(model, opt_cfg: OptimizerConfig, mesh,
                                 axis: str = "pod"):
     """train_step(params, opt_state, err, batch) -> (params, opt, err, metrics)
@@ -127,8 +74,6 @@ def build_compressed_train_step(model, opt_cfg: OptimizerConfig, mesh,
     n_pods = mesh.shape[axis]
 
     def grads_fn(params, batch, err):
-        if not hasattr(jax, "shard_map"):
-            return _grads_fn_vmapped(model, params, batch, err, n_pods)
         # manual over `axis` only; data/model stay auto
         def inner(params, batch, err):
             loss, g = jax.value_and_grad(model.loss)(params, batch)
@@ -157,11 +102,11 @@ def build_compressed_train_step(model, opt_cfg: OptimizerConfig, mesh,
         spec_rep = jax.tree_util.tree_map(lambda _: P(), params)
         spec_err = jax.tree_util.tree_map(lambda _: P(axis), err)
         spec_batch = jax.tree_util.tree_map(lambda _: P(axis), batch)
-        return _shard_map(
-            inner, mesh,
+        return jax.shard_map(
+            inner, mesh=mesh,
             in_specs=(spec_rep, spec_batch, spec_err),
             out_specs=(P(), spec_rep, spec_err),
-            manual_axes={axis},
+            axis_names={axis}, check_vma=False,
         )(params, batch, err)
 
     def train_step(params, opt_state, err, batch):

@@ -41,15 +41,10 @@ __all__ = [
 
 
 def abstract_mesh(axis_sizes, axis_names) -> AbstractMesh:
-    """Construct an ``AbstractMesh`` across the JAX signature change.
-
-    Current JAX takes ``AbstractMesh(axis_sizes, axis_names)``; 0.4.x takes a
-    single ``shape_tuple`` of ``(name, size)`` pairs.
-    """
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    """Device-free mesh with ``Auto`` axes, like ``launch.mesh.make_mesh``."""
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names),
+                        axis_types=(jax.sharding.AxisType.Auto,)
+                        * len(axis_names))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -6,20 +6,5 @@ oracle (ref.py) and a jit'd public wrapper (ops.py).
                      score materialization the roofline analysis surfaces).
   coflow_assign    — the paper's tau-aware greedy cross-core assignment
                      (Alg. 1 lines 5-17) with VMEM-resident scheduler state.
-
-``tpu_compiler_params`` papers over the JAX API rename
-``pltpu.TPUCompilerParams`` -> ``pltpu.CompilerParams`` (jax 0.4.x exposes
-only the former, current releases only the latter) so the kernels compile on
-either side of the drift.
 """
-import jax.experimental.pallas.tpu as _pltpu
-
 from . import ref  # noqa: F401
-
-
-def tpu_compiler_params(**kwargs):
-    """Build the TPU Pallas compiler-params object across JAX versions."""
-    cls = getattr(_pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = _pltpu.TPUCompilerParams
-    return cls(**kwargs)

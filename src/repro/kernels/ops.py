@@ -1,9 +1,11 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On this CPU container kernels execute in interpret mode (set
-``REPRO_PALLAS_INTERPRET=1``, which the test-suite does); on real TPU the
-same calls compile to Mosaic. The wrapper signatures match the XLA reference
-paths so models can switch implementation per-config.
+On a TPU the calls compile to Mosaic. Anywhere else they raise, unless
+``REPRO_PALLAS_INTERPRET=1`` asks for the Pallas interpreter (the test suite
+sets it in ``tests/conftest.py``): a kernel never falls back to the
+interpreter on its own, so no CPU timing passes for a device run. The
+wrapper signatures match the XLA reference paths so models can switch
+implementation per-config.
 """
 from __future__ import annotations
 
@@ -15,13 +17,20 @@ import jax.numpy as jnp
 from repro.kernels.coflow_assign import coflow_assign_fwd
 from repro.kernels.flash_attention import flash_attention_fwd
 
-__all__ = ["flash_attention", "coflow_assign"]
+__all__ = ["flash_attention", "coflow_assign", "interpret_mode"]
 
 
-def _interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
+def interpret_mode() -> bool:
+    """True when interpret mode was asked for; raises when there is no TPU."""
+    if os.environ.get("REPRO_PALLAS_INTERPRET") == "1":
         return True
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"no TPU found (JAX backend is {backend!r}): the Pallas kernels "
+            f"run on a TPU, or in the interpreter with "
+            f"REPRO_PALLAS_INTERPRET=1")
+    return False
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, softmax_scale=None,
@@ -44,7 +53,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, softmax_scale=None,
         bk //= 2
     return flash_attention_fwd(
         q, k, v, causal=causal, window=window, softmax_scale=softmax_scale,
-        block_q=max(bq, 1), block_k=max(bk, 1), interpret=_interpret())
+        block_q=max(bq, 1), block_k=max(bk, 1), interpret=interpret_mode())
 
 
 def coflow_assign(fi, fj, sizes, rates, delta, *, n_ports, block_f=256):
@@ -60,4 +69,4 @@ def coflow_assign(fi, fj, sizes, rates, delta, *, n_ports, block_f=256):
     return coflow_assign_fwd(
         jnp.asarray(fi, jnp.int32), jnp.asarray(fj, jnp.int32),
         jnp.asarray(sizes, jnp.float32), jnp.asarray(rates, jnp.float32),
-        float(delta), n_ports=n_ports, block_f=block_f, interpret=_interpret())
+        float(delta), n_ports=n_ports, block_f=block_f, interpret=interpret_mode())

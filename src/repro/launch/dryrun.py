@@ -1,8 +1,10 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import: jax locks the device
-# count at first initialization. The dry-run (and only the dry-run) builds the
-# 512-way production meshes on CPU stand-in devices.
+# The lines above MUST run before any other import: jax locks the platform and
+# device count at first initialization. The dry-run (and only the dry-run)
+# builds the 512-way production meshes on CPU stand-in devices, and never
+# takes a chip.
 """Multi-pod dry-run driver.
 
 For every assigned (architecture x input shape) cell and each production mesh

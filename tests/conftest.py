@@ -9,12 +9,18 @@ whole point is to run the property suites as property tests. The full
 lane sets the variable (after installing requirements-dev.txt), so a
 broken dev-install fails loudly at collection time instead of quietly
 downgrading coverage.
+
+The suite runs on the CPU, so the Pallas kernels run in the interpreter:
+``REPRO_PALLAS_INTERPRET=1`` asks ``repro.kernels.ops`` for it (without it the
+kernel wrappers raise off-TPU).
 """
 from __future__ import annotations
 
 import os
 
 import pytest
+
+os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 
 
 def pytest_configure(config: pytest.Config) -> None:

@@ -274,6 +274,17 @@ def test_run_batch_oracle_both_backends():
         assert len(tab) == 2 and all(r.weighted_cct > 0 for r in tab)
 
 
+def test_run_batch_pallas_stays_in_process():
+    """A chip belongs to one process: pallas sweeps never fan out."""
+    insts = [_random_instance(s) for s in range(4)]
+    tab = run_batch(insts, ("ours",), check="none", backend="pallas",
+                    materialize="metrics")  # default workers: 4 grid points
+    assert len(tab) == 4
+    with pytest.raises(ValueError, match="holds the chip"):
+        run_batch(insts, ("ours",), check="none", backend="pallas",
+                  materialize="metrics", workers=2)
+
+
 def test_build_flow_table_backends_agree_small():
     """fp32 vs fp64 tie decisions agree on a small instance."""
     inst = _random_instance(2)

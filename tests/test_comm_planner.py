@@ -50,8 +50,9 @@ def test_planner_on_compiled_step():
         from repro.models.common import activation_sharding
         from repro.analysis.hlo import analyze_hlo
         from repro.comm import BlockMap, step_coflows, plan_circuits
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = ModelConfig(name="m", family="moe", n_layers=2, d_model=64,
                           n_heads=4, n_kv_heads=2, d_ff=96, vocab=128,
                           n_experts=4, top_k=2)

@@ -184,6 +184,7 @@ def test_elastic_trainer_survives_device_loss(tmp_path):
         from repro.train.optimizer import OptimizerConfig, init_opt_state
         from repro.train.step import build_train_step
         from repro.distributed.sharding import TRAIN_RULES, plan_tree
+        from repro.launch.mesh import make_mesh
         cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=32,
                           n_heads=2, n_kv_heads=2, d_ff=64, vocab=61,
                           dtype=jnp.float32)
@@ -206,9 +207,9 @@ def test_elastic_trainer_survives_device_loss(tmp_path):
                         "v": psh, "step": None}}}}
             return step_fn, make_state, shardings_of
 
-        meshes = [jax.make_mesh((4, 2), ("data", "model")),
-                  jax.make_mesh((2, 2), ("data", "model"),
-                                devices=jax.devices()[:4])]
+        meshes = [make_mesh((4, 2), ("data", "model")),
+                  make_mesh((2, 2), ("data", "model"),
+                            devices=jax.devices()[:4])]
         tr = ElasticTrainer(build, meshes, {str(tmp_path)!r}, ckpt_every=5)
 
         def batches():

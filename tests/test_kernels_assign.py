@@ -47,6 +47,24 @@ def test_kernel_single_block_small_f():
     np.testing.assert_array_equal(np.asarray(out), ref_c)
 
 
+def test_kernel_resolves_bounds_below_fp32_resolution():
+    """Two bounds that fp32 cannot tell apart still pick the oracle's core.
+
+    After three flows core 0's bound is 2**24 + 1 and core 1's is 2**24; in
+    one fp32 word both round to 2**24 and the tie would go to core 0. The
+    kernel's two-word state sees the gap and, like fp64 ``assign_ref``,
+    sends the fourth flow to core 1.
+    """
+    fi = np.array([0, 0, 0, 1], np.int32)
+    sz = np.array([2.0 ** 24, 2.0 ** 24, 1.0, 0.25], np.float32)
+    rates = np.array([1.0, 1.0], np.float32)
+    ref_c, _ = assign_ref(fi, fi, sz, rates, 0.0, 2)
+    np.testing.assert_array_equal(ref_c, [0, 1, 0, 1])
+    out = coflow_assign_fwd(jnp.array(fi), jnp.array(fi), jnp.array(sz),
+                            jnp.array(rates), 0.0, n_ports=2, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), ref_c)
+
+
 @pytest.mark.parametrize("case", CASES, ids=[str(c) for c in CASES])
 def test_kernel_matches_oracle(case):
     F, K, N, delta, bf = case
@@ -90,10 +108,10 @@ def test_kernel_matches_oracle_hypothesis(K, N, F, delta, seed):
 def test_kernel_large_f_precision_contract():
     """Stress the fp32 precision contract at large F (see coflow_assign_fwd).
 
-    The kernel accumulates loads/bounds in fp32 while assign_ref/CoreState
-    accumulate in fp64, so argmin tie decisions can diverge once partial sums
-    grow (large F) or sizes spread over orders of magnitude (heavy-tailed
-    trace demands). This test quantifies the contract end-to-end on a
+    The kernel takes flow sizes in fp32 while assign_fast/CoreState work on
+    the fp64 sizes, so argmin tie decisions can diverge once near-ties meet
+    the input rounding (large F, heavy-tailed trace demands). This test
+    quantifies the contract end-to-end on a
     trace-scale instance: the choice-agreement rate must stay high (>97%)
     and the induced weighted-CCT gap must stay small (<2%) — divergences are
     tie-break artifacts, not algorithmic errors.
@@ -155,3 +173,14 @@ def test_kernel_matches_core_on_trace_instance():
         interpret=True))
     agree = (out == want).mean()
     assert agree > 0.99, f"only {agree:.3f} agreement with core implementation"
+
+
+def test_no_silent_interpreter_off_tpu(monkeypatch):
+    """Off-TPU the kernel wrapper raises unless the interpreter is asked for."""
+    from repro.kernels.ops import coflow_assign
+
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    with pytest.raises(RuntimeError, match="no TPU found"):
+        coflow_assign(np.zeros(4, np.int32), np.ones(4, np.int32),
+                      np.ones(4, np.float32), np.array([10.0, 20.0]), 2.0,
+                      n_ports=8)
