@@ -47,7 +47,7 @@ from .coflow import Coflow, Instance, OnlineInstance, extract_flows
 from .effects import effects
 from .ordering import order_coflows, priority_scores
 from .scheduler import Schedule
-from repro.obs.trace import NULL_TRACER, Tracer
+from repro.obs.trace import NULL_TRACER, Tracer, current_tracer
 
 if TYPE_CHECKING:   # runtime import would cycle: fault.py imports engine
     from .fault import FaultApplication, FaultEvent, FaultInjector
@@ -145,9 +145,11 @@ def _pallas_choices(inst: Instance, flows: tuple[np.ndarray, ...]) -> np.ndarray
 
     _pos, _cid, fi, fj, sizes = flows
     out = coflow_assign(fi, fj, sizes, inst.rates, inst.delta, n_ports=inst.N)
-    return np.asarray(out, dtype=np.int64)
+    with current_tracer().span("oneshot/assign/fetch"):
+        return np.asarray(out, dtype=np.int64)
 
 
+@effects("rng-consume", "trace-emit")
 def build_flow_table(
     inst: Instance,
     pi: Annotated[I8, "M"],
@@ -189,23 +191,34 @@ def build_flow_table(
             raise ValueError(
                 f"delta_k must have shape ({inst.K},), got {delta_k.shape}")
     policy, _ = _resolve_algorithm(algorithm, "")
-    flows = extract_flows(inst, pi)
-    if (policy == "tau-aware" and delta_k is not None
-            and bool(np.any(delta_k != inst.delta))):  # reprolint: disable=float-eq -- identity check: delta_k entries are copied config/fault values, not arithmetic
-        from .assignment import FlatAssignState
+    tracer = current_tracer()
+    with tracer.span("oneshot/extract") as sp:
+        flows = extract_flows(inst, pi)
+        if sp.live:
+            sp.set(flows=int(flows[0].size))
+    drifted = (policy == "tau-aware" and delta_k is not None
+               and bool(np.any(delta_k != inst.delta)))  # reprolint: disable=float-eq -- identity check: delta_k entries are copied config/fault values, not arithmetic
+    on_kernel = (not drifted and backend == "pallas"
+                 and policy == "tau-aware" and not locality)
+    with tracer.span("oneshot/assign") as sp:
+        if sp.live:
+            sp.set(flows=int(flows[0].size),
+                   impl="pallas" if on_kernel else "numpy")
+        if drifted:
+            from .assignment import FlatAssignState
 
-        st = FlatAssignState(policy, inst.rates, inst.delta, inst.N,
-                             seed=seed, locality=locality)
-        for k in range(inst.K):
-            if delta_k[k] != inst.delta:  # reprolint: disable=float-eq -- identity check: only overridden cores get a set_delta call
-                st.set_delta(k, float(delta_k[k]))
-        _pos, _cid, fi, fj, sizes = flows
-        core = st.assign(fi, fj, sizes)
-    elif backend == "pallas" and policy == "tau-aware" and not locality:
-        core = _pallas_choices(inst, flows)
-    else:
-        core = assign_fast(inst, pi, policy, seed=seed, flows=flows,
-                           locality=locality)
+            st = FlatAssignState(policy, inst.rates, inst.delta, inst.N,
+                                 seed=seed, locality=locality)
+            for k in range(inst.K):
+                if delta_k[k] != inst.delta:  # reprolint: disable=float-eq -- identity check: only overridden cores get a set_delta call
+                    st.set_delta(k, float(delta_k[k]))
+            _pos, _cid, fi, fj, sizes = flows
+            core = st.assign(fi, fj, sizes)
+        elif on_kernel:
+            core = _pallas_choices(inst, flows)
+        else:
+            core = assign_fast(inst, pi, policy, seed=seed, flows=flows,
+                               locality=locality)
     pos, cid, fi, fj, size = flows
     return FlowTable(pos=pos, cid=cid, fi=fi, fj=fj, core=core, size=size)
 
@@ -240,6 +253,19 @@ def _pop_next_event(events: list, t: float) -> float:
     return heapq.heappop(events)
 
 
+@dataclasses.dataclass
+class LoopCounts:
+    """What merged event loops did, summed over calls (telemetry only:
+    nothing the engine computes reads it back)."""
+
+    #: heap pops, stale entries (times already passed) included
+    events: int = 0
+    #: flows gathered as candidates at events (after dedup, before the
+    #: ones already started are dropped); with ``guard=True``, the pending
+    #: flows of the cores active at each event
+    candidates: int = 0
+
+
 def _event_loop(
     rin: np.ndarray,       # (F,) int64 ingress resource ids (core*N + i)
     rout: np.ndarray,      # (F,) int64 egress resource ids (core*N + j)
@@ -253,6 +279,7 @@ def _event_loop(
     release: np.ndarray | None = None,
     free_in0: np.ndarray | None = None,
     free_out0: np.ndarray | None = None,
+    counts: LoopCounts | None = None,
 ) -> np.ndarray:
     """Vectorized merged event loop; flows are in priority order per core.
 
@@ -293,6 +320,9 @@ def _event_loop(
     ``delta`` is a scalar, or a per-flow ``(F,)`` array when cores have
     drifted reconfiguration delays (``fault.DeltaDrift``); the scalar path
     computes the exact same float expressions as before.
+
+    ``counts``, when given, has this call's heap pops and candidates
+    added to it once, at the end (see :class:`LoopCounts`).
     """
     F = rin.size
     t_est = np.full(F, -1.0)
@@ -323,6 +353,10 @@ def _event_loop(
             np.argsort(rel_inv, kind="stable"),
             np.cumsum(np.bincount(rel_inv))[:-1])
         rel_map = {float(v): lst for v, lst in zip(rel_uniq, rel_lists)}
+    # every flow pushes one completion, so the pops are the pushes less
+    # what is left in the heap at the end
+    n_pushed = len(events) + F
+    n_cand = 0
 
     if guard:
         pending = np.arange(F)
@@ -340,6 +374,7 @@ def _event_loop(
                 if release is not None:
                     act[core[pending[release[pending] == t]]] = True  # reprolint: disable=float-eq -- exact-float convention: event times are copied release values, never arithmetic
                 pend = pending[act[core[pending]]]
+                n_cand += pend.size
             if release is not None and pend.size:
                 pend = pend[release[pend] <= t]
             if pend.size:
@@ -363,6 +398,9 @@ def _event_loop(
                     if not remaining:
                         break
             t = _pop_next_event(events, t)
+        if counts is not None:
+            counts.events += n_pushed - len(events)
+            counts.candidates += n_cand
         return t_est
 
     in_lists = _by_resource(rin, n_res)
@@ -397,9 +435,13 @@ def _event_loop(
         if release is not None:
             pool.append(rel_map.get(t, np.empty(0, np.int64)))
         cand = np.unique(np.concatenate(pool)) if pool else np.empty(0, np.int64)
+        n_cand += cand.size
         cand = cand[~done[cand]]
         if release is not None:
             cand = cand[release[cand] <= t]
+    if counts is not None:
+        counts.events += n_pushed - len(events)
+        counts.candidates += n_cand
     return t_est
 
 
@@ -450,6 +492,7 @@ def _sunflow_times(
     release: np.ndarray | None = None,
     prio: np.ndarray | None = None,
     delta_k: Annotated[F8, "K"] | None = None,
+    counts: LoopCounts | None = None,
 ) -> np.ndarray:
     """SUNFLOW-CORE: per core, coflows strictly sequential (barrier), flows of
     one coflow scheduled largest-first.
@@ -502,12 +545,14 @@ def _sunflow_times(
             te = _event_loop(
                 rin[grp], rout[grp], srv[grp], table.core[grp], dk,
                 n_res=K * n_ports, n_ports=n_ports, t0=barrier, guard=True,
+                counts=counts,
             )
             t_est[grp] = te
             barrier = max(barrier, float(((te + dk) + srv[grp]).max()))
     return t_est
 
 
+@effects("trace-emit")
 def _times_for_table(
     inst: Instance,
     pi: np.ndarray,
@@ -529,54 +574,62 @@ def _times_for_table(
     replaces the uniform ``inst.delta`` with ``delta_k[core]`` per flow.
     ``None`` (or an all-nominal vector, which callers should normalize to
     ``None``) computes the exact pre-drift floats.
-    """
-    K, N = inst.K, inst.N
-    rin = table.core * N + table.fi
-    rout = table.core * N + table.fj
-    srv = table.size / inst.rates[table.core]
-    dl = inst.delta if delta_k is None \
-        else np.asarray(delta_k, dtype=np.float64)[table.core]
-    if scheduling not in SCHEDULINGS:
-        raise ValueError(
-            f"unknown scheduling {scheduling!r}; one of {SCHEDULINGS}")
-    if releases is None:
-        if scheduling == "work-conserving":
-            t_est = _event_loop(rin, rout, srv, table.core, dl, K * N, N)
-        elif scheduling == "priority-guard":
-            t_est = _event_loop(rin, rout, srv, table.core, dl, K * N, N,
-                                guard=True)
-        elif scheduling == "reserving":
-            t_est = _reserving_times(rin, rout, srv, dl, K * N)
-        elif scheduling == "sunflow":
-            t_est = _sunflow_times(table, rin, rout, srv, inst.delta, N, K,
-                                   delta_k=delta_k)
-    else:
-        from .online import online_orders
 
-        rel_orig = np.asarray(releases, dtype=np.float64)
-        orig = np.asarray(pi)[table.pos]
-        rel_f = rel_orig[orig]
-        _, prio_rank = online_orders(inst, rel_orig)
-        prio_f = prio_rank[orig]
-        if scheduling in ("work-conserving", "priority-guard"):
-            # The event loop wants flows in scheduling-priority order: WSPT
-            # coflow rank, then the intra-coflow assignment order (stable).
-            perm = np.argsort(prio_f, kind="stable")
-            te = _event_loop(
-                rin[perm], rout[perm], srv[perm], table.core[perm],
-                dl if delta_k is None else dl[perm], K * N, N,
-                guard=(scheduling == "priority-guard"),
-                release=rel_f[perm])
-            t_est = np.empty_like(te)
-            t_est[perm] = te
-        elif scheduling == "reserving":
-            # commitment in arrival order == the FlowTable's native order
-            t_est = _reserving_times(rin, rout, srv, dl, K * N,
-                                     release=rel_f)
-        elif scheduling == "sunflow":
-            t_est = _sunflow_times(table, rin, rout, srv, inst.delta, N, K,
-                                   release=rel_f, prio=prio_f,
-                                   delta_k=delta_k)
+    Traced as ``oneshot/event_loop``, with the event loops' ``events`` and
+    ``candidates`` (:class:`LoopCounts`) unless the policy is reserving.
+    """
+    with current_tracer().span("oneshot/event_loop") as sp:
+        counts = LoopCounts() if sp.live else None
+        K, N = inst.K, inst.N
+        rin = table.core * N + table.fi
+        rout = table.core * N + table.fj
+        srv = table.size / inst.rates[table.core]
+        dl = inst.delta if delta_k is None \
+            else np.asarray(delta_k, dtype=np.float64)[table.core]
+        if scheduling not in SCHEDULINGS:
+            raise ValueError(
+                f"unknown scheduling {scheduling!r}; one of {SCHEDULINGS}")
+        if releases is None:
+            if scheduling == "work-conserving":
+                t_est = _event_loop(rin, rout, srv, table.core, dl, K * N, N,
+                                    counts=counts)
+            elif scheduling == "priority-guard":
+                t_est = _event_loop(rin, rout, srv, table.core, dl, K * N, N,
+                                    guard=True, counts=counts)
+            elif scheduling == "reserving":
+                t_est = _reserving_times(rin, rout, srv, dl, K * N)
+            elif scheduling == "sunflow":
+                t_est = _sunflow_times(table, rin, rout, srv, inst.delta, N, K,
+                                       delta_k=delta_k, counts=counts)
+        else:
+            from .online import online_orders
+
+            rel_orig = np.asarray(releases, dtype=np.float64)
+            orig = np.asarray(pi)[table.pos]
+            rel_f = rel_orig[orig]
+            _, prio_rank = online_orders(inst, rel_orig)
+            prio_f = prio_rank[orig]
+            if scheduling in ("work-conserving", "priority-guard"):
+                # The event loop wants flows in scheduling-priority order: WSPT
+                # coflow rank, then the intra-coflow assignment order (stable).
+                perm = np.argsort(prio_f, kind="stable")
+                te = _event_loop(
+                    rin[perm], rout[perm], srv[perm], table.core[perm],
+                    dl if delta_k is None else dl[perm], K * N, N,
+                    guard=(scheduling == "priority-guard"),
+                    release=rel_f[perm], counts=counts)
+                t_est = np.empty_like(te)
+                t_est[perm] = te
+            elif scheduling == "reserving":
+                # commitment in arrival order == the FlowTable's native order
+                t_est = _reserving_times(rin, rout, srv, dl, K * N,
+                                         release=rel_f)
+            elif scheduling == "sunflow":
+                t_est = _sunflow_times(table, rin, rout, srv, inst.delta, N, K,
+                                       release=rel_f, prio=prio_f,
+                                       delta_k=delta_k, counts=counts)
+        if counts is not None and scheduling != "reserving":
+            sp.set(events=counts.events, candidates=counts.candidates)
     return t_est, srv
 
 
@@ -594,6 +647,7 @@ def _ccts_from_times(inst: Instance, pi: np.ndarray, table: FlowTable,
     return ccts
 
 
+@effects("trace-emit")
 def _schedule_from_times(
     inst: Instance,
     pi: np.ndarray,
@@ -605,28 +659,30 @@ def _schedule_from_times(
 ) -> Schedule:
     """Materialize ScheduledFlow records in the legacy order: core-major,
     priority order within each core (schedule_core_sunflow emits coflow
-    groups in pi order too, so core-major pi order matches it as well)."""
-    order = np.lexsort((np.arange(table.n_flows), table.core))
-    flows = []
-    for f in order:
-        te = float(t_est[f])
-        s = float(table.size[f])
-        rate = float(inst.rates[table.core[f]])
-        dl = inst.delta if delta_f is None else float(delta_f[f])
-        flows.append(
-            ScheduledFlow(
-                coflow=int(table.pos[f]),
-                cid=int(table.cid[f]),
-                i=int(table.fi[f]),
-                j=int(table.fj[f]),
-                core=int(table.core[f]),
-                size=s,
-                t_establish=te,
-                t_start=te + dl,
-                t_complete=te + dl + s / rate,
+    groups in pi order too, so core-major pi order matches it as well).
+    Traced as ``oneshot/schedule``."""
+    with current_tracer().span("oneshot/schedule"):
+        order = np.lexsort((np.arange(table.n_flows), table.core))
+        flows = []
+        for f in order:
+            te = float(t_est[f])
+            s = float(table.size[f])
+            rate = float(inst.rates[table.core[f]])
+            dl = inst.delta if delta_f is None else float(delta_f[f])
+            flows.append(
+                ScheduledFlow(
+                    coflow=int(table.pos[f]),
+                    cid=int(table.cid[f]),
+                    i=int(table.fi[f]),
+                    j=int(table.fj[f]),
+                    core=int(table.core[f]),
+                    size=s,
+                    t_establish=te,
+                    t_start=te + dl,
+                    t_complete=te + dl + s / rate,
+                )
             )
-        )
-    ccts = _ccts_from_times(inst, pi, table, t_est, srv, delta_f)
+        ccts = _ccts_from_times(inst, pi, table, t_est, srv, delta_f)
     return Schedule(inst=inst, pi=pi, assignment=assignment, flows=flows, ccts=ccts)
 
 
@@ -670,6 +726,7 @@ def _normalize_delta_k(inst: Instance,
     return delta_k
 
 
+@effects("rng-consume", "trace-emit")
 def run_fast(
     inst: Instance,
     algorithm: str = "ours",
@@ -701,7 +758,8 @@ def run_fast(
     comparisons, not bit-exactness (see DESIGN.md §Delta-scheduling).
     """
     delta_k = _normalize_delta_k(inst, delta_k)
-    pi = order_coflows(inst)
+    with current_tracer().span("oneshot/order"):
+        pi = order_coflows(inst)
     _, scheduling = _resolve_algorithm(algorithm, scheduling)
     table = build_flow_table(inst, pi, algorithm, seed=seed, backend=backend,
                              delta_k=delta_k, locality=locality)
@@ -711,6 +769,7 @@ def run_fast(
     return _schedule_from_times(inst, pi, None, table, t_est, srv, dl_f)
 
 
+@effects("rng-consume", "trace-emit")
 def run_fast_metrics(
     inst: Instance,
     algorithm: str = "ours",
@@ -731,13 +790,14 @@ def run_fast_metrics(
     derive from these, which is what ``run_batch(materialize="metrics")``
     consumes at trace scale.
     """
-    if releases is None:
-        pi = order_coflows(inst)
-    else:
-        from .online import online_orders
+    with current_tracer().span("oneshot/order"):
+        if releases is None:
+            pi = order_coflows(inst)
+        else:
+            from .online import online_orders
 
-        releases = np.asarray(releases, dtype=np.float64)
-        pi, _ = online_orders(inst, releases)
+            releases = np.asarray(releases, dtype=np.float64)
+            pi, _ = online_orders(inst, releases)
     delta_k = _normalize_delta_k(inst, delta_k)
     _, scheduling = _resolve_algorithm(algorithm, scheduling)
     table = build_flow_table(inst, pi, algorithm, seed=seed, backend=backend,
@@ -748,6 +808,7 @@ def run_fast_metrics(
     return _ccts_from_times(inst, pi, table, t_est, srv, dl_f), table.n_flows
 
 
+@effects("rng-consume", "trace-emit")
 def run_fast_online(
     oinst: OnlineInstance,
     algorithm: str = "ours",
@@ -775,7 +836,8 @@ def run_fast_online(
     from .online import online_orders
 
     delta_k = _normalize_delta_k(inst, delta_k)
-    arrival, _ = online_orders(inst, rel)
+    with current_tracer().span("oneshot/order"):
+        arrival, _ = online_orders(inst, rel)
     _, scheduling = _resolve_algorithm(algorithm, scheduling)
     table = build_flow_table(inst, arrival, algorithm, seed=seed,
                              backend=backend, delta_k=delta_k,
@@ -1772,6 +1834,7 @@ class FabricState:
                 # set; a component's restriction equals the global order's
                 # restriction because components share no resources.
                 with self._tracer.span("tick/event_loop") as sp_ev:
+                    counts = LoopCounts() if sp_ev.live else None
                     perm = np.lexsort((pend["intra"][sub], pend["gid"][sub],
                                        -pend["score"][sub]))
                     s = sub[perm]
@@ -1781,10 +1844,12 @@ class FabricState:
                         self.N, t0=t_prev,
                         guard=(self.scheduling == "priority-guard"),
                         release=pend["rel"][s],
-                        free_in0=self.free_in, free_out0=self.free_out)
+                        free_in0=self.free_in, free_out0=self.free_out,
+                        counts=counts)
                     t_est[s] = te
-                    if sp_ev.live:
-                        sp_ev.set(rows=int(sub.size))
+                    if counts is not None:
+                        sp_ev.set(rows=int(sub.size), events=counts.events,
+                                  candidates=counts.candidates)
             commit = t_est <= t_now
         if dl_f is None:
             tc = (t_est[commit] + self.delta) + pend["srv"][commit]

@@ -43,7 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-__all__ = ["coflow_assign_fwd", "MAX_CORES", "MAX_PORTS", "VMEM_LIMIT_BYTES"]
+__all__ = ["coflow_assign_fwd", "padded_flows", "MAX_CORES", "MAX_PORTS",
+           "VMEM_LIMIT_BYTES"]
 
 #: Largest core count the VMEM budget below is sized for.
 MAX_CORES = 8
@@ -174,6 +175,12 @@ def _assign_kernel(fi_ref, fj_ref, sz_ref, delta_ref, rates_ref, out_ref,
                                      jnp.zeros((1, bf), jnp.int32))
 
 
+def padded_flows(f: int, block_f: int) -> int:
+    """The kernel's flow count: ``f`` padded to whole blocks of
+    ``min(block_f, f)`` flows."""
+    return f + (-f) % min(block_f, f) if f else 0
+
+
 @functools.partial(jax.jit,
                    static_argnames=("n_ports", "block_f", "interpret"))
 def coflow_assign_fwd(
@@ -231,7 +238,7 @@ def coflow_assign_fwd(
         # which pallas_call rejects; there is nothing to assign.
         return jnp.zeros((0,), jnp.int32)
     bf = min(block_f, f)
-    pad = (-f) % bf
+    pad = padded_flows(f, block_f) - f
     if pad:
         fi = jnp.concatenate([fi, jnp.zeros((pad,), fi.dtype)])
         fj = jnp.concatenate([fj, jnp.zeros((pad,), fj.dtype)])
@@ -244,6 +251,7 @@ def coflow_assign_fwd(
                               memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         kernel,
+        name="coflow_assign",
         grid=(nb,),
         in_specs=[
             flow_block,  # fi

@@ -14,8 +14,10 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.coflow_assign import coflow_assign_fwd
+from repro.core.effects import effects
+from repro.kernels.coflow_assign import coflow_assign_fwd, padded_flows
 from repro.kernels.flash_attention import flash_attention_fwd
+from repro.obs.trace import current_tracer
 
 __all__ = ["flash_attention", "coflow_assign", "interpret_mode"]
 
@@ -56,6 +58,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, softmax_scale=None,
         block_q=max(bq, 1), block_k=max(bk, 1), interpret=interpret_mode())
 
 
+@effects("trace-emit")
 def coflow_assign(fi, fj, sizes, rates, delta, *, n_ports, block_f=256):
     """Tau-aware greedy assignment; returns per-flow core choices (F,) int32.
 
@@ -65,8 +68,19 @@ def coflow_assign(fi, fj, sizes, rates, delta, *, n_ports, block_f=256):
     kernel's int32/fp32 here). Inherits the fp32 precision contract of
     ``coflow_assign_fwd``: choices can diverge from the fp64 oracles on
     near-tie flows at large F; use the numpy backend for bit-reproducibility.
+
+    Traced as ``oneshot/assign/put`` (the casts and transfers) and
+    ``oneshot/assign/launch`` (the jitted call, which returns before the
+    device finishes).
     """
-    return coflow_assign_fwd(
-        jnp.asarray(fi, jnp.int32), jnp.asarray(fj, jnp.int32),
-        jnp.asarray(sizes, jnp.float32), jnp.asarray(rates, jnp.float32),
-        float(delta), n_ports=n_ports, block_f=block_f, interpret=interpret_mode())
+    tracer = current_tracer()
+    with tracer.span("oneshot/assign/put"):
+        args = (jnp.asarray(fi, jnp.int32), jnp.asarray(fj, jnp.int32),
+                jnp.asarray(sizes, jnp.float32),
+                jnp.asarray(rates, jnp.float32))
+    with tracer.span("oneshot/assign/launch") as sp:
+        if sp.live:
+            sp.set(padded_flows=padded_flows(args[0].shape[0], block_f))
+        return coflow_assign_fwd(
+            *args, float(delta), n_ports=n_ports, block_f=block_f,
+            interpret=interpret_mode())

@@ -6,6 +6,8 @@ flushed to a JSONL sink, and exportable as a Chrome-trace / Perfetto
 ``traceEvents`` document. The span taxonomy the fabric emits (see
 DESIGN.md §Observability):
 
+Streaming plane (``FabricManager.tick``):
+
   ``tick``                 one ``FabricManager`` service tick (root)
   ``tick/admit``           admission-queue drain under the flow budget
   ``tick/assign``          batch registration + core assignment
@@ -15,29 +17,65 @@ DESIGN.md §Observability):
                            staled — plus ``components_total`` /
                            ``components_touched``)
   ``tick/event_loop``      the vectorized event loop over touched rows
+                           (``rows``, ``events``, ``candidates``)
   ``tick/program_emit``    circuit-program compilation (+ referee)
   ``fault/recover``        one fault application (abort/requeue counts +
                            ``invalidated``: tentative rows the scoped
                            invalidation staled, see DESIGN.md
                            §Delta-scheduling)
-  ``cache/hit|miss|purge`` one-shot program-cache traffic (events)
+  ``cache/purge``          one-shot program-cache purge (event)
+
+One-shot plane (``FabricManager.schedule_instance``; the engine's free
+functions record into :func:`current_tracer`, which the manager sets to
+its own tracer for the call):
+
+  ``oneshot``              the whole request (``coflows``, ``flows``,
+                           ``hit``, ``compiles``: XLA compilations during
+                           the call that the persistent cache did not
+                           answer, see :func:`compile_count`)
+  ``oneshot/key``          fabric fingerprint, ``instance_key``, lookup
+  ``oneshot/order``        ``order_coflows`` / ``online_orders``
+  ``oneshot/extract``      ``extract_flows`` (``flows``)
+  ``oneshot/assign``       core assignment, either implementation
+                           (``flows``, ``impl``: ``pallas`` / ``numpy``)
+  ``oneshot/assign/put``   the kernel inputs' casts and transfers
+  ``oneshot/assign/launch``  the jitted kernel call, which returns before
+                           the device finishes (``padded_flows``)
+  ``oneshot/assign/fetch`` the wait for the device and the read-back
+  ``oneshot/event_loop``   ``_times_for_table`` (``events``: heap pops,
+                           ``candidates``: flows gathered at events)
+  ``oneshot/schedule``     ``_schedule_from_times``
+  ``oneshot/emit``         ``compile_schedule`` (``segments``)
+  ``oneshot/cache``        relabelling and the cache put
+
+Profiler bridge: whenever a JAX profiler session is active
+(``jax.profiler.TraceAnnotation.is_enabled()``), every span — of a
+recording :class:`Tracer` and of :data:`NULL_TRACER` alike — is also a
+``TraceAnnotation`` named ``fabric/<span>``, its attributes riding along
+as the annotation's metadata, so it lands in the profiler trace on the
+device trace's clock. In-memory records keep :mod:`repro.obs.clock`
+timestamps. JAX is never imported for this: before anything imports
+``jax.profiler`` no session can be active.
 
 Determinism contract: the tracer only *observes* — all timestamps come
 from the sanctioned :mod:`repro.obs.clock` boundary and no instrumented
 code path reads a span back, so schedules are bit-identical with tracing
 on or off (``tests/test_obs.py`` asserts this differentially, including
-a fault-injected run).
+a fault-injected run, and ``tests/test_obs_profiler.py`` with a profiler
+session on).
 
 Overhead contract: the disabled path is allocation-free. The global
 default is :data:`NULL_TRACER`, whose ``span()`` returns one shared
-no-op span object and whose ``event()`` returns immediately; call sites
-compute attributes only behind ``span.live`` / ``tracer.enabled``
-guards, so a manager with tracing off does no per-tick tracing work
-beyond a few attribute loads and no-op calls.
+no-op span object (after one ``is_enabled()`` check) and whose
+``event()`` returns immediately; call sites compute attributes only
+behind ``span.live`` / ``tracer.enabled`` guards, so a manager with
+tracing off does no per-tick tracing work beyond a few attribute loads
+and no-op calls.
 """
 from __future__ import annotations
 
 import json
+import sys
 from pathlib import Path
 from typing import IO, Iterator
 
@@ -45,8 +83,60 @@ from .clock import now
 
 __all__ = [
     "Span", "Tracer", "NullTracer", "NULL_TRACER",
-    "current_tracer", "set_tracer", "to_chrome_trace",
+    "current_tracer", "set_tracer", "to_chrome_trace", "compile_count",
 ]
+
+#: Name prefix of the spans' copies in a profiler trace.
+PROFILER_PREFIX = "fabric/"
+
+#: ``jax.profiler.TraceAnnotation`` once ``jax.profiler`` is imported.
+_ANNOTATION: type | None = None
+
+
+def _profiler_annotation(name: str) -> object | None:
+    """A ``fabric/<name>`` annotation while a profiler session is active,
+    else ``None`` (one ``is_enabled()`` check)."""
+    global _ANNOTATION
+    cls = _ANNOTATION
+    if cls is None:
+        mod = sys.modules.get("jax.profiler")
+        if mod is None:
+            return None  # nothing imported jax.profiler: no session
+        cls = _ANNOTATION = mod.TraceAnnotation
+    if not cls.is_enabled():  # type: ignore[attr-defined]
+        return None
+    return cls(PROFILER_PREFIX + name)
+
+
+#: JAX's monitoring events behind :func:`compile_count`.
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+#: [backend compiles requested, persistent-cache hits] since registration
+_COMPILES: list[int] | None = None
+
+
+def compile_count() -> int:
+    """XLA compilations in this process that the persistent compilation
+    cache did not answer, counted from JAX's monitoring events since the
+    first call (which imports ``jax.monitoring`` and registers the one
+    listener); a difference of two calls counts what ran between them."""
+    global _COMPILES
+    if _COMPILES is None:
+        import jax.monitoring as monitoring
+
+        counts = _COMPILES = [0, 0]
+
+        def on_duration(event: str, _secs: float, **_kw: object) -> None:
+            if event == _BACKEND_COMPILE_EVENT:
+                counts[0] += 1
+
+        def on_event(event: str, **_kw: object) -> None:
+            if event == _CACHE_HIT_EVENT:
+                counts[1] += 1
+
+        monitoring.register_event_duration_secs_listener(on_duration)
+        monitoring.register_event_listener(on_event)
+    return _COMPILES[0] - _COMPILES[1]
 
 
 def _jsonable_attr(v: object) -> object:
@@ -67,33 +157,72 @@ class Span:
 
     ``live`` is True on real spans and False on the shared no-op span —
     instrumented code guards attribute computation behind it so the
-    disabled path stays free.
+    disabled path stays free. ``ann`` is the span's profiler copy (see
+    the module docstring), or ``None`` outside a profiler session.
     """
 
-    __slots__ = ("_tracer", "name", "sid", "parent", "depth", "t0", "attrs")
+    __slots__ = ("_tracer", "name", "sid", "parent", "depth", "t0", "attrs",
+                 "_ann")
 
     live: bool = True
 
     def __init__(self, tracer: "Tracer", name: str, sid: int,
-                 parent: int | None, depth: int) -> None:
+                 parent: int | None, depth: int,
+                 ann: object | None = None) -> None:
         self._tracer = tracer
         self.name = name
         self.sid = sid
         self.parent = parent
         self.depth = depth
+        self._ann = ann
         self.t0 = now()
         self.attrs: dict[str, object] = {}
 
     def set(self, **attrs: object) -> "Span":
         """Attach typed attributes (recorded when the span closes)."""
         self.attrs.update(attrs)
+        if self._ann is not None:
+            _annotate(self._ann, attrs)
         return self
 
     def __enter__(self) -> "Span":
+        if self._ann is not None:
+            self._ann.__enter__()  # type: ignore[attr-defined]
         return self
 
     def __exit__(self, exc_type: object, exc: object, tb: object) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)  # type: ignore[attr-defined]
         self._tracer._close(self, error=exc_type is not None)
+        return False
+
+
+def _annotate(ann: object, attrs: dict[str, object]) -> None:
+    ann.set_metadata(  # type: ignore[attr-defined]
+        **{k: _jsonable_attr(v) for k, v in attrs.items()})
+
+
+class _ProfilerSpan:
+    """A span of :data:`NULL_TRACER` during a profiler session: the
+    profiler copy alone, nothing kept in memory."""
+
+    __slots__ = ("_ann",)
+
+    live: bool = True
+
+    def __init__(self, ann: object) -> None:
+        self._ann = ann
+
+    def set(self, **attrs: object) -> "_ProfilerSpan":
+        _annotate(self._ann, attrs)
+        return self
+
+    def __enter__(self) -> "_ProfilerSpan":
+        self._ann.__enter__()  # type: ignore[attr-defined]
+        return self
+
+    def __exit__(self, exc_type: object, exc: object, tb: object) -> bool:
+        self._ann.__exit__(exc_type, exc, tb)  # type: ignore[attr-defined]
         return False
 
 
@@ -145,7 +274,8 @@ class Tracer:
         sid = self._next_sid
         self._next_sid += 1
         parent = self._stack[-1].sid if self._stack else None
-        sp = Span(self, name, sid, parent, depth=len(self._stack))
+        sp = Span(self, name, sid, parent, depth=len(self._stack),
+                  ann=_profiler_annotation(name))
         self._stack.append(sp)
         return sp
 
@@ -218,7 +348,9 @@ class NullTracer(Tracer):
     """The disabled tracer: every operation is a no-op.
 
     ``span()`` returns the one shared :data:`NULL_SPAN` instance, so the
-    disabled hot path allocates nothing; ``records`` stays empty.
+    disabled hot path allocates nothing; ``records`` stays empty. During a
+    profiler session it returns a span that records into the profiler
+    trace only.
     """
 
     enabled = False
@@ -227,7 +359,10 @@ class NullTracer(Tracer):
         super().__init__(sink=None)
 
     def span(self, name: str) -> Span:
-        return NULL_SPAN  # type: ignore[return-value]
+        ann = _profiler_annotation(name)
+        if ann is None:
+            return NULL_SPAN  # type: ignore[return-value]
+        return _ProfilerSpan(ann)  # type: ignore[return-value]
 
     def event(self, name: str, **attrs: object) -> None:
         return None

@@ -92,20 +92,17 @@ class ProgramCache:
     def __len__(self) -> int:
         return len(self._store)
 
-    @effects("cache-read", "trace-emit")
+    @effects("cache-read")
     def get(self, key: str) -> object | None:
-        """Program for ``key``, or None (counts a hit/miss either way)."""
+        """Program for ``key``, or None (counts a hit/miss either way; the
+        ``oneshot`` span of the request carries ``hit`` too)."""
         try:
             val = self._store[key]
         except KeyError:
             self._misses.inc()
-            if self._tracer.enabled:
-                self._tracer.event("cache/miss", key=key[:16])
             return None
         self._store.move_to_end(key)
         self._hits.inc()
-        if self._tracer.enabled:
-            self._tracer.event("cache/hit", key=key[:16])
         return val
 
     @effects("cache-write")

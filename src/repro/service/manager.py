@@ -43,7 +43,7 @@ from repro.core.engine import (
 )
 from repro.obs.clock import now
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import Tracer, current_tracer
+from repro.obs.trace import Tracer, compile_count, current_tracer, set_tracer
 
 from .admission import (
     AdmissionPolicy,
@@ -426,7 +426,36 @@ class FabricManager:
         Returns ``(program, hit)`` — on a hit the engine never runs; the
         cached program is the byte-identical artifact of the earlier
         computation (the pipeline is deterministic in the hashed inputs).
+
+        Traced as ``oneshot`` and its sub-spans (``repro.obs.trace``): the
+        manager's tracer is the process's current tracer for the call, so
+        the engine's free functions record into it.
         """
+        tracer = self._tracer
+        prev = set_tracer(tracer)
+        try:
+            with tracer.span("oneshot") as sp:
+                c0 = compile_count() if sp.live else 0
+                program, hit = self._schedule_instance(
+                    inst, algorithm, scheduling, seed, backend)
+                if sp.live:
+                    base = inst.inst if isinstance(inst, OnlineInstance) \
+                        else inst
+                    sp.set(coflows=base.M, flows=program.n_segments, hit=hit,
+                           compiles=compile_count() - c0)
+        finally:
+            set_tracer(prev)
+        return program, hit
+
+    def _schedule_instance(
+        self,
+        inst: Instance | OnlineInstance,
+        algorithm: str | None,
+        scheduling: str | None,
+        seed: int | None,
+        backend: str,
+    ) -> tuple[CircuitProgram, bool]:
+        tracer = self._tracer
         algorithm = self.config.algorithm if algorithm is None else algorithm
         scheduling = self.config.scheduling if scheduling is None else scheduling
         seed = self.config.seed if seed is None else seed
@@ -446,21 +475,18 @@ class FabricManager:
         degraded = not bool(up.all())
         drifted = self.state.delta_drifted
         delta_k = self.state.delta_k.copy() if drifted else None
-        fp = []
-        if degraded:
-            fp.append("up=" + "".join("1" if u else "0" for u in up))
-        if drifted:
-            fp.append("delta_k="
-                      + ",".join(repr(float(d)) for d in delta_k))
-        fingerprint = ";".join(fp)
-        key = instance_key(inst, releases, algorithm=algorithm,
-                           scheduling=scheduling, seed=seed, backend=backend,
-                           fabric=fingerprint)
-        # The cache stores programs labeled by coflow INDEX (canonical: the
-        # key excludes cid labels, so a hit may come from a submission with
-        # different cids); relabel to this caller's ids with one lookup.
-        sub_cids = np.array([c.cid for c in inst.coflows], dtype=np.int64)
-        canonical = self.cache.get(key)
+        with tracer.span("oneshot/key"):
+            fp = []
+            if degraded:
+                fp.append("up=" + "".join("1" if u else "0" for u in up))
+            if drifted:
+                fp.append("delta_k="
+                          + ",".join(repr(float(d)) for d in delta_k))
+            fingerprint = ";".join(fp)
+            key = instance_key(inst, releases, algorithm=algorithm,
+                               scheduling=scheduling, seed=seed,
+                               backend=backend, fabric=fingerprint)
+            canonical = self.cache.get(key)
         hit = canonical is not None
         if not hit:
             run_inst = inst
@@ -502,11 +528,19 @@ class FabricManager:
                 canonical = dataclasses.replace(
                     canonical, rates=np.asarray(inst.rates, dtype=np.float64),
                     core=up_idx[canonical.core])
-        program = dataclasses.replace(canonical, cid=sub_cids[canonical.cid])
-        if not hit:
-            if self.config.validate_every_tick:
-                program.validate()  # before caching: never store unvetted
-            self.cache.put(key, canonical)
+        with tracer.span("oneshot/cache"):
+            # The cache stores programs labeled by coflow INDEX (canonical:
+            # the key excludes cid labels, so a hit may come from a
+            # submission with different cids); relabel to this caller's ids
+            # with one lookup.
+            sub_cids = np.array([c.cid for c in inst.coflows],
+                                dtype=np.int64)
+            program = dataclasses.replace(canonical,
+                                          cid=sub_cids[canonical.cid])
+            if not hit:
+                if self.config.validate_every_tick:
+                    program.validate()  # before caching: never store unvetted
+                self.cache.put(key, canonical)
         return program, hit
 
     def sweep_instances(self, instances: Sequence[Instance],

@@ -24,7 +24,9 @@ import numpy as np
 from repro.core.arrays import F8, I8
 from repro.core.circuit_scheduler import ScheduledFlow
 from repro.core.coflow import Coflow, Instance
+from repro.core.effects import effects
 from repro.core.scheduler import Schedule
+from repro.obs.trace import current_tracer
 
 if TYPE_CHECKING:
     from repro.core.engine import TickCommit
@@ -245,6 +247,7 @@ def compile_commit(commit: "TickCommit", rates: Annotated[F8, "K"],
                            commit.t_complete, commit.delta_f)
 
 
+@effects("trace-emit")
 def compile_schedule(s: Schedule, *, index_labels: bool = False) -> CircuitProgram:
     """Compile a full ``Schedule`` (e.g. the one-shot cached path).
 
@@ -252,19 +255,24 @@ def compile_schedule(s: Schedule, *, index_labels: bool = False) -> CircuitProgr
     instance index instead of its ``cid`` — the canonical form the program
     cache stores, since indices are unique by construction and map to any
     later submission's cids with one array lookup.
+
+    Traced as ``oneshot/emit`` (``segments``).
     """
-    F = len(s.flows)
-    if F == 0:
-        return CircuitProgram.empty(s.inst.rates, s.inst.delta, s.inst.N)
-    get = lambda attr, dt: np.fromiter(
-        (getattr(f, attr) for f in s.flows),
-        dtype=dt, count=F)
-    if index_labels:
-        labels = np.asarray(s.pi, dtype=np.int64)[get("coflow", np.int64)]
-    else:
-        labels = get("cid", np.int64)
-    return _sorted_program(
-        s.inst.rates, s.inst.delta, s.inst.N,
-        get("core", np.int64), get("i", np.int64), get("j", np.int64),
-        labels, get("size", np.float64),
-        get("t_establish", np.float64), get("t_complete", np.float64))
+    with current_tracer().span("oneshot/emit") as sp:
+        F = len(s.flows)
+        if sp.live:
+            sp.set(segments=F)
+        if F == 0:
+            return CircuitProgram.empty(s.inst.rates, s.inst.delta, s.inst.N)
+        get = lambda attr, dt: np.fromiter(
+            (getattr(f, attr) for f in s.flows),
+            dtype=dt, count=F)
+        if index_labels:
+            labels = np.asarray(s.pi, dtype=np.int64)[get("coflow", np.int64)]
+        else:
+            labels = get("cid", np.int64)
+        return _sorted_program(
+            s.inst.rates, s.inst.delta, s.inst.N,
+            get("core", np.int64), get("i", np.int64), get("j", np.int64),
+            labels, get("size", np.float64),
+            get("t_establish", np.float64), get("t_complete", np.float64))

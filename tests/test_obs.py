@@ -241,9 +241,9 @@ def test_cache_traffic_emits_events_and_counters():
     _, hit0 = mgr.schedule_instance(oinst)
     _, hit1 = mgr.schedule_instance(oinst)
     assert (hit0, hit1) == (False, True)
-    events = [r["name"] for r in tr.records if r["kind"] == "event"]
-    assert events.count("cache/miss") == 1
-    assert events.count("cache/hit") == 1
+    requests = [r["attrs"] for r in tr.records if r["name"] == "oneshot"]
+    assert [a["hit"] for a in requests] == [False, True]
+    assert not [r for r in tr.records if r["kind"] == "event"]
     s = mgr.summary()
     assert s["cache_hits"] == 1 and s["cache_misses"] == 1
     assert mgr.metrics.snapshot()["cache.hits"] == 1
