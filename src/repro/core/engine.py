@@ -12,8 +12,10 @@ id, so one merged event loop drives the whole machine:
   - per event, the set of flows that the sequential priority scan would start
     is computed with vector masks: a flow starts iff it is the first pending
     candidate on *both* its resources (iterated to a fixed point for the
-    work-conserving policy — the classic locally-first parallelisation of
-    greedy list scheduling, which provably reproduces the sequential scan);
+    work-conserving policy's first event — the classic locally-first
+    parallelisation of greedy list scheduling, which provably reproduces the
+    sequential scan); the work-conserving policy's later events pick their
+    starts from the pending lists of the resources freed then;
   - only cores with a completion at the current event time are touched, so
     the merged loop keeps the legacy per-core work complexity.
 
@@ -27,6 +29,7 @@ within tolerance.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import heapq
 from typing import TYPE_CHECKING, Annotated, Sequence
@@ -237,11 +240,32 @@ def _first_occurrence(vals: np.ndarray, scratch: np.ndarray) -> np.ndarray:
     return scratch[vals] == np.arange(n)
 
 
-def _by_resource(res_ids: np.ndarray, n_res: int) -> list[np.ndarray]:
-    """Flow indices using each resource, in priority (index) order."""
+def _by_resource(flows: np.ndarray, res_ids: np.ndarray, other: np.ndarray,
+                 n_res: int) -> tuple[list[list], list[list]]:
+    """Per resource, the ``flows`` (ascending) that use it, in priority
+    (index) order, and beside them each one's ``other`` resource; both as
+    Python lists, sliced from two bulk conversions."""
     order = np.argsort(res_ids, kind="stable")
-    counts = np.bincount(res_ids, minlength=n_res)
-    return np.split(order, np.cumsum(counts)[:-1])
+    ends = np.cumsum(np.bincount(res_ids, minlength=n_res)).tolist()
+    fl = flows[order].tolist()
+    ol = other[order].tolist()
+    starts = [0] + ends[:-1]
+    return ([fl[s:e] for s, e in zip(starts, ends)],
+            [ol[s:e] for s, e in zip(starts, ends)])
+
+
+def _scan_pending(others: list, flows: list, free: list, t: float, q: int,
+                  rel: list | None) -> tuple[int, int]:
+    """Scan one resource's pending list from position ``q`` for its first
+    flow that can start at ``t``: its other resource free (``others``), and
+    with ``rel`` its release reached. Returns (entries examined, that
+    flow's position or -1)."""
+    q0 = q
+    for o in (others if q == 0 else others[q:]):
+        if free[o] <= t and (rel is None or rel[flows[q]] <= t):
+            return q - q0 + 1, q
+        q += 1
+    return q - q0, -1
 
 
 def _pop_next_event(events: list, t: float) -> float:
@@ -260,9 +284,11 @@ class LoopCounts:
 
     #: heap pops, stale entries (times already passed) included
     events: int = 0
-    #: flows gathered as candidates at events (after dedup, before the
-    #: ones already started are dropped); with ``guard=True``, the pending
-    #: flows of the cores active at each event
+    #: pending flows examined at events after the first: by the
+    #: work-conserving loop's scans of the freed resources' pending lists
+    #: (each entry once an event and list) and of the flows released then;
+    #: with ``guard=True``, the pending flows of the cores active at each
+    #: event
     candidates: int = 0
 
 
@@ -286,24 +312,38 @@ def _event_loop(
     Returns t_establish per flow. Exactly reproduces the legacy sequential
     scan: at each event, the started set is {flows whose two resources are
     free and which are the first pending user of both} — iterated to a fixed
-    point for guard=False, single-pass for guard=True (where a pending
-    higher-priority flow makes both its resources unavailable whether or not
-    it starts, so "first on both" is already the full answer).
+    point for guard=False (at its first event; later events reach the same
+    set by the per-resource picks below), single-pass for guard=True (where
+    a pending higher-priority flow makes both its resources unavailable
+    whether or not it starts, so "first on both" is already the full
+    answer).
 
-    Work-conserving fast path: after each event's fixed point, every pending
-    flow has at least one busy resource (else it would have started), so a
-    flow can only become startable at an event where one of its resources
-    completes *exactly then*. Candidates are therefore gathered from the
-    per-resource flow lists of just-freed resources instead of rescanning
-    the whole pending set — per-event cost scales with port occupancy, not
-    with total remaining flows.
+    Work-conserving fast path: after each event, every pending flow has at
+    least one busy resource (else it would have started), so a flow can
+    only become startable at an event where one of its resources completes
+    *exactly then*. The first event runs the fixed point over every flow;
+    later ones pick their starts per freed resource (:func:`_pick_starts`).
+    Each resource keeps the list of its pending flows in priority order (a
+    flow leaves both its lists when it starts). For each resource freed at
+    ``t`` and still free, its pick is the first flow of its list whose
+    other resource is free (and, online, whose release has come); the
+    lowest-index pick starts, and the picks are
+    renewed, until no resource yields one. That is the sequential scan over
+    the flows of the freed resources' lists: a flow of theirs that cannot
+    start when the scan reaches it cannot start later at the same event,
+    since resources only get busier, so the lowest-index flow that can
+    start now is the next one the scan starts; it lies on a freed resource
+    that is still free, and no flow before it on that list can start, so it
+    is that resource's pick. Per-event work scales with the examined prefix
+    of a couple of lists, not with total remaining flows.
 
     ``release`` (per flow) adds online release gating: a flow is eligible
     only at events ``t >= release[f]`` (exact float comparison, same
     convention as ``circuit_scheduler``). Release times are seeded into the
     event heap, extending the invariant above: a pending flow either has a
-    busy resource or an unreached release, so candidates at an event are
-    gathered from just-freed resources plus flows released exactly then. An
+    busy resource or an unreached release, so the flows that can start at
+    an event lie on just-freed resources or were released exactly then (the
+    first of those whose resources are both free is one more pick). An
     unreleased flow never protects its ports under ``guard=True`` (the
     online scheduler cannot know flows that have not arrived).
 
@@ -319,7 +359,9 @@ def _event_loop(
 
     ``delta`` is a scalar, or a per-flow ``(F,)`` array when cores have
     drifted reconfiguration delays (``fault.DeltaDrift``); the scalar path
-    computes the exact same float expressions as before.
+    computes the exact same float expressions as before (a scalar enters
+    the per-resource picks as a Python float: exact for a float, an int or
+    a float64).
 
     ``counts``, when given, has this call's heap pops and candidates
     added to it once, at the end (see :class:`LoopCounts`).
@@ -348,11 +390,6 @@ def _event_loop(
         rel_uniq, rel_inv = np.unique(release, return_inverse=True)
         events.extend(rel_uniq.tolist())
         heapq.heapify(events)
-        # flow indices grouped by release value, in priority order
-        rel_lists = np.split(
-            np.argsort(rel_inv, kind="stable"),
-            np.cumsum(np.bincount(rel_inv))[:-1])
-        rel_map = {float(v): lst for v, lst in zip(rel_uniq, rel_lists)}
     # every flow pushes one completion, so the pops are the pushes less
     # what is left in the heap at the end
     n_pushed = len(events) + F
@@ -403,46 +440,141 @@ def _event_loop(
             counts.candidates += n_cand
         return t_est
 
-    in_lists = _by_resource(rin, n_res)
-    out_lists = _by_resource(rout, n_res)
-    cand = np.arange(F)  # at t0 every flow is a candidate
+    # The first event: every (released) flow is a candidate.
+    cand = np.arange(F)
     if release is not None:
         cand = cand[release[cand] <= t]
-    while remaining:
+    cand = cand[(free_in[rin[cand]] <= t) & (free_out[rout[cand]] <= t)]
+    while cand.size:
+        safe = _first_occurrence(rin[cand], scratch) \
+            & _first_occurrence(rout[cand], scratch)
+        start = cand[safe]
+        tc = (t + (delta if d_vec is None else d_vec[start])) + srv[start]
+        free_in[rin[start]] = tc
+        free_out[rout[start]] = tc
+        t_est[start] = t
+        done[start] = True
+        remaining -= start.size
+        for v in tc.tolist():
+            heapq.heappush(events, v)
+        cand = cand[~safe]
         cand = cand[(free_in[rin[cand]] <= t) & (free_out[rout[cand]] <= t)]
-        while cand.size:
-            safe = _first_occurrence(rin[cand], scratch) \
-                & _first_occurrence(rout[cand], scratch)
-            start = cand[safe]
-            tc = (t + (delta if d_vec is None else d_vec[start])) + srv[start]
-            free_in[rin[start]] = tc
-            free_out[rout[start]] = tc
-            t_est[start] = t
-            done[start] = True
-            remaining -= start.size
-            for v in tc.tolist():
-                heapq.heappush(events, v)
-            cand = cand[~safe]
-            cand = cand[(free_in[rin[cand]] <= t) & (free_out[rout[cand]] <= t)]
-        if not remaining:
-            break
-        t = _pop_next_event(events, t)
-        # Gather candidates from the flow lists of resources freed exactly
-        # at t, plus flows released exactly at t (see the invariant in the
-        # docstring).
-        pool = [in_lists[r] for r in np.nonzero(free_in == t)[0]]  # reprolint: disable=float-eq -- exact-float convention: t is popped verbatim from the event heap fed by free_in
-        pool += [out_lists[r] for r in np.nonzero(free_out == t)[0]]  # reprolint: disable=float-eq -- exact-float convention: t is popped verbatim from the event heap fed by free_out
-        if release is not None:
-            pool.append(rel_map.get(t, np.empty(0, np.int64)))
-        cand = np.unique(np.concatenate(pool)) if pool else np.empty(0, np.int64)
-        n_cand += cand.size
-        cand = cand[~done[cand]]
-        if release is not None:
-            cand = cand[release[cand] <= t]
+    if remaining:
+        t_est, n_cand = _pick_starts(rin, rout, srv, delta, n_res, t, events,
+                                     free_in, free_out, t_est, done,
+                                     remaining, release)
     if counts is not None:
         counts.events += n_pushed - len(events)
         counts.candidates += n_cand
     return t_est
+
+
+def _pick_starts(
+    rin: np.ndarray, rout: np.ndarray, srv: np.ndarray,
+    delta: float | np.ndarray, n_res: int, t: float, events: list,
+    free_in: np.ndarray, free_out: np.ndarray, t_est: np.ndarray,
+    done: np.ndarray, remaining: int, release: np.ndarray | None,
+) -> tuple[np.ndarray, int]:
+    """The work-conserving loop's events after the first (``t``), by the
+    per-freed-resource pick that :func:`_event_loop` describes.
+
+    Ingress resource ``r`` is ``r`` here, egress resource ``r`` is
+    ``n_res + r``; state lives in Python lists, since an event touches a
+    handful of scalars. A dict from completion time to the resources it
+    frees (seeded from the horizons still ahead of ``t``) stands in for
+    scanning every resource for ``free == t``; ``events`` keeps one heap
+    entry per completion, so its pops are counted as before. Returns
+    ``t_est`` and the entries the scans examined.
+    """
+    pend = np.flatnonzero(~done)
+    lists, others = _by_resource(
+        np.concatenate([pend, pend]),
+        np.concatenate([rin[pend], rout[pend] + n_res]),
+        np.concatenate([rout[pend] + n_res, rin[pend]]), 2 * n_res)
+    A = rin.tolist()
+    B = (rout + n_res).tolist()
+    free_all = np.concatenate([free_in, free_out])
+    free = free_all.tolist()
+    freeing: dict[float, list] = {}
+    ahead = np.flatnonzero((free_all > t) & np.isfinite(free_all))
+    for r, v in zip(ahead.tolist(), free_all[ahead].tolist()):
+        freeing.setdefault(v, []).append(r)
+    te = t_est.tolist()
+    started = done.tolist()
+    sv = srv.tolist()
+    if np.ndim(delta) == 0:
+        delta, d_l = float(delta), None
+    else:
+        d_l = np.asarray(delta, dtype=np.float64).tolist()
+    rel = rel_map = None
+    if release is not None:
+        rel = release.tolist()
+        # flow indices grouped by release value, in priority order
+        rel_map = {}
+        for f in np.argsort(release, kind="stable").tolist():
+            rel_map.setdefault(rel[f], []).append(f)
+    scan = _scan_pending
+    n_cand = 0
+    while remaining:
+        t = _pop_next_event(events, t)
+        # picks: (flow, resource or -1 for the flows released at t, the
+        # flow's position in that list)
+        picks = []
+        for r in freeing.pop(t, ()):
+            n, q = scan(others[r], lists[r], free, t, 0, rel)
+            n_cand += n
+            if q >= 0:
+                picks.append((lists[r][q], r, q))
+        rel_now = None if rel_map is None else rel_map.get(t)
+        if rel_now is not None:
+            # this scan always runs to the end of its list within the event
+            n_cand += len(rel_now)
+            for q, f in enumerate(rel_now):
+                if free[A[f]] <= t and free[B[f]] <= t:
+                    picks.append((f, -1, q))
+                    break
+        while picks:
+            f = min(picks)[0]
+            tc = (t + (delta if d_l is None else d_l[f])) + sv[f]
+            a, b = A[f], B[f]
+            free[a] = tc
+            free[b] = tc
+            te[f] = t
+            started[f] = True
+            remaining -= 1
+            heapq.heappush(events, tc)
+            frees = freeing.get(tc)
+            if frees is None:
+                freeing[tc] = [a, b]
+            else:
+                frees += (a, b)
+            for r in (a, b):
+                q = bisect.bisect_left(lists[r], f)
+                del lists[r][q], others[r][q]
+            # renew the picks: a busy resource has none; a pick that can
+            # still start stands; else scan on from after it
+            renewed = []
+            for g, r, q in picks:
+                if r >= 0:
+                    if free[r] > t:
+                        continue
+                    if g != f and free[others[r][q]] <= t:
+                        renewed.append((g, r, q))
+                        continue
+                    n, q = scan(others[r], lists[r], free, t,
+                                q if g == f else q + 1, rel)
+                    n_cand += n
+                    if q >= 0:
+                        renewed.append((lists[r][q], r, q))
+                else:
+                    for q in range(q, len(rel_now)):
+                        h = rel_now[q]
+                        if (not started[h] and free[A[h]] <= t
+                                and free[B[h]] <= t):
+                            renewed.append((h, -1, q))
+                            break
+            picks = renewed
+    return np.array(te), n_cand
 
 
 def _reserving_times(

@@ -17,7 +17,9 @@ Streaming plane (``FabricManager.tick``):
                            staled — plus ``components_total`` /
                            ``components_touched``)
   ``tick/event_loop``      the vectorized event loop over touched rows
-                           (``rows``, ``events``, ``candidates``)
+                           (``rows``, ``events``, ``candidates``: pending
+                           flows examined at events, see
+                           ``engine.LoopCounts``)
   ``tick/program_emit``    circuit-program compilation (+ referee)
   ``fault/recover``        one fault application (abort/requeue counts +
                            ``invalidated``: tentative rows the scoped
@@ -43,7 +45,8 @@ its own tracer for the call):
                            the device finishes (``padded_flows``)
   ``oneshot/assign/fetch`` the wait for the device and the read-back
   ``oneshot/event_loop``   ``_times_for_table`` (``events``: heap pops,
-                           ``candidates``: flows gathered at events)
+                           ``candidates``: pending flows examined at
+                           events, see ``engine.LoopCounts``)
   ``oneshot/schedule``     ``_schedule_from_times``
   ``oneshot/emit``         ``compile_schedule`` (``segments``)
   ``oneshot/cache``        relabelling and the cache put

@@ -214,9 +214,11 @@ def test_programs_bit_identical_profiler_and_tracer(tmp_path, backend):
 def _recount(rin, rout, core, srv, delta, t_est, release=None, guard=False):
     """Heap pops and candidates of one from-scratch loop (t0 = 0), from the
     times it produced: the loop pops every heap entry before its last start
-    time T, and one entry equal to T; at each distinct popped time it
-    gathers the users of the resources freed then (and the flows released
-    then), or under ``guard`` the pending flows of the cores active then."""
+    time T, and one entry equal to T. At each distinct popped time t, under
+    ``guard`` it counts the pending flows of the cores active then; else it
+    scans the pending flows (started at or after t) of each resource freed
+    then, in index order, up to the one that starts on that resource at t,
+    or all of them if none does, and every flow released then."""
     tc = (t_est + delta) + srv
     entries = tc.tolist()
     if release is not None:
@@ -232,15 +234,19 @@ def _recount(rin, rout, core, srv, delta, t_est, release=None, guard=False):
         ending = tc == t
         rel_now = (np.zeros(rin.size, bool) if release is None
                    else release == t)
+        pending = t_est >= t
         if guard:
-            pending = t_est >= t
             act = set(core[ending].tolist()) | set(
                 core[pending & rel_now].tolist())
             cand += int((pending & np.isin(core, list(act))).sum())
-        else:
-            got = (np.isin(rin, rin[ending]) | np.isin(rout, rout[ending])
-                   | rel_now)
-            cand += int(got.sum())
+            continue
+        for res in (rin, rout):
+            for r in np.unique(res[ending]).tolist():
+                scanned = np.flatnonzero(pending & (res == r))
+                now = scanned[t_est[scanned] == t]
+                cand += (int(np.searchsorted(scanned, now[0])) + 1
+                         if now.size else scanned.size)
+        cand += int(rel_now.sum())
     return events, cand
 
 
@@ -272,16 +278,48 @@ def test_loop_counts_equal_a_plain_recount(seed, online, guard):
 
 def test_serial_flows_pop_one_event_a_flow():
     """Flows sharing one ingress port run one after another: the times are
-    distinct and the loop pops F - 1 events, each gathering every flow."""
+    distinct and the loop pops F - 1 events. When flow f ends, the scan of
+    the shared ingress port examines one flow, f + 1, which starts; that
+    of f's egress port examines its later flows, none of which can start
+    once f + 1 holds the ingress port."""
     F = 12
     rin = np.zeros(F, np.int64)
     rout = np.arange(F, dtype=np.int64) % 4
     srv = np.linspace(1.0, 3.0, F)
     counts = LoopCounts()
-    _event_loop(rin, rout, srv, np.zeros(F, np.int64), 8.0, 4, 4,
-                counts=counts)
+    t_est = _event_loop(rin, rout, srv, np.zeros(F, np.int64), 8.0, 4, 4,
+                        counts=counts)
+    assert np.all(np.diff(t_est) > 0)
     assert counts.events == F - 1
-    assert counts.candidates == (F - 1) * F
+    later_on_egress = sum(len(range(f + 4, F, 4)) for f in range(F - 1))
+    assert counts.candidates == (F - 1) + later_on_egress
+
+
+@pytest.mark.parametrize("online", [False, True])
+@pytest.mark.parametrize("seed", range(4))
+def test_started_flow_is_never_examined_again(monkeypatch, seed, online):
+    """Every flow a resource scan examines at an event t is pending then
+    (starts at t or later), and no event examines one list entry twice:
+    a flow leaves its lists when it starts."""
+    from repro.core import engine
+
+    seen = []
+    scan = engine._scan_pending
+
+    def recording(others, flows, free, t, q, rel):
+        n, hit = scan(others, flows, free, t, q, rel)
+        seen.extend((t, id(flows), f) for f in flows[q:q + n])
+        return n, hit
+
+    monkeypatch.setattr(engine, "_scan_pending", recording)
+    rin, rout, srv, core, n_res, n_ports, release = _random_loop_input(
+        seed, F=120, N=3, online=online)
+    counts = LoopCounts()
+    t_est = _event_loop(rin, rout, srv, core, 8.0, n_res, n_ports,
+                        release=release, counts=counts)
+    assert seen and len(seen) <= counts.candidates
+    assert all(t_est[f] >= t for t, _lst, f in seen)
+    assert len(set(seen)) == len(seen)
 
 
 def test_one_shot_event_loop_span_carries_the_counts():
