@@ -191,7 +191,7 @@ def row_from_ccts(idx: int, alg: str, sched: str, seed: int,
 def _row_from_schedule(idx: int, alg: str, sched: str, seed: int,
                        s: Schedule, wall: float) -> SweepRow:
     return row_from_ccts(idx, alg, sched, seed, s.inst.weights, s.ccts,
-                         len(s.flows), wall)
+                         s.n_flows, wall)
 
 
 def run_batch(

@@ -34,6 +34,7 @@ import numpy as np
 
 __all__ = [
     "ScheduledFlow",
+    "records_built",
     "schedule_core_list",
     "schedule_core_sunflow",
     "schedule_core_reserving",
@@ -51,6 +52,23 @@ class ScheduledFlow:
     t_establish: float  # circuit establishment begins (ports become busy)
     t_start: float      # transmission begins = t_establish + delta
     t_complete: float   # t_establish + delta + size/rate
+
+    def __post_init__(self) -> None:
+        global _built
+        _built += 1
+
+
+#: ``ScheduledFlow`` records constructed by this process so far.
+_built = 0
+
+
+def records_built() -> int:
+    """How many ``ScheduledFlow`` records this process has built so far.
+
+    The one-shot service reads the difference across a request
+    (``service.flows_materialized``): its path keeps flows as columns, so
+    the difference is 0 unless a caller asks for the objects."""
+    return _built
 
 
 def _run_list_scheduler(

@@ -45,11 +45,10 @@ from .assignment import (
     assignment_from_choices,
 )
 from .arrays import F8, I8
-from .circuit_scheduler import ScheduledFlow
 from .coflow import Coflow, Instance, OnlineInstance, extract_flows
 from .effects import effects
 from .ordering import order_coflows, priority_scores
-from .scheduler import Schedule
+from .scheduler import FlowColumns, Schedule
 from repro.obs.trace import NULL_TRACER, Tracer, current_tracer
 
 if TYPE_CHECKING:   # runtime import would cycle: fault.py imports engine
@@ -789,33 +788,27 @@ def _schedule_from_times(
     srv: np.ndarray,
     delta_f: np.ndarray | None = None,
 ) -> Schedule:
-    """Materialize ScheduledFlow records in the legacy order: core-major,
+    """The schedule's flows as columns in the legacy order: core-major,
     priority order within each core (schedule_core_sunflow emits coflow
     groups in pi order too, so core-major pi order matches it as well).
+    Start and completion times are the per-flow record's float expressions,
+    ``(t_est + delta) + size / rate``, elementwise. No ``ScheduledFlow`` is
+    built here; ``Schedule.flows`` builds them if a caller reads it.
     Traced as ``oneshot/schedule``."""
     with current_tracer().span("oneshot/schedule"):
         order = np.lexsort((np.arange(table.n_flows), table.core))
-        flows = []
-        for f in order:
-            te = float(t_est[f])
-            s = float(table.size[f])
-            rate = float(inst.rates[table.core[f]])
-            dl = inst.delta if delta_f is None else float(delta_f[f])
-            flows.append(
-                ScheduledFlow(
-                    coflow=int(table.pos[f]),
-                    cid=int(table.cid[f]),
-                    i=int(table.fi[f]),
-                    j=int(table.fj[f]),
-                    core=int(table.core[f]),
-                    size=s,
-                    t_establish=te,
-                    t_start=te + dl,
-                    t_complete=te + dl + s / rate,
-                )
-            )
+        core = table.core[order]
+        size = table.size[order]
+        t_e = np.asarray(t_est, dtype=np.float64)[order]
+        t_start = t_e + (inst.delta if delta_f is None else delta_f[order])
+        columns = FlowColumns(
+            coflow=table.pos[order], cid=table.cid[order],
+            i=table.fi[order], j=table.fj[order], core=core, size=size,
+            t_establish=t_e, t_start=t_start,
+            t_complete=t_start + size / inst.rates[core])
         ccts = _ccts_from_times(inst, pi, table, t_est, srv, delta_f)
-    return Schedule(inst=inst, pi=pi, assignment=assignment, flows=flows, ccts=ccts)
+    return Schedule(inst=inst, pi=pi, assignment=assignment, ccts=ccts,
+                    columns=columns)
 
 
 def schedule_all_cores(

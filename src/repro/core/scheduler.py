@@ -12,10 +12,11 @@ independent implementation is the point.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
+from typing import Annotated, Callable
 
 import numpy as np
 
+from .arrays import F8, I8
 from .assignment import Assignment, assign_random, assign_rho_only, assign_tau_aware
 from .circuit_scheduler import (
     ScheduledFlow,
@@ -26,11 +27,48 @@ from .circuit_scheduler import (
 from .coflow import Instance
 from .ordering import order_coflows
 
-__all__ = ["Schedule", "run", "ALGORITHMS", "weighted_cct", "tail_cct",
-           "tail_quantile"]
+__all__ = ["FlowColumns", "Schedule", "run", "ALGORITHMS", "weighted_cct",
+           "tail_cct", "tail_quantile"]
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(frozen=True)
+class FlowColumns:
+    """A schedule's flows as flat arrays, one entry a flow, in the order of
+    ``Schedule.flows``; each field is the ``ScheduledFlow`` field of the
+    same name (``coflow`` .. ``core`` int64, the rest float64)."""
+
+    coflow: Annotated[I8, "F"]
+    cid: Annotated[I8, "F"]
+    i: Annotated[I8, "F"]
+    j: Annotated[I8, "F"]
+    core: Annotated[I8, "F"]
+    size: Annotated[F8, "F"]
+    t_establish: Annotated[F8, "F"]
+    t_start: Annotated[F8, "F"]
+    t_complete: Annotated[F8, "F"]
+
+    @property
+    def n_flows(self) -> int:
+        return int(self.core.size)
+
+    @classmethod
+    def from_flows(cls, flows: list[ScheduledFlow]) -> "FlowColumns":
+        n = len(flows)
+        return cls(*(
+            np.fromiter((getattr(r, name) for r in flows),
+                        dtype=np.int64 if name in _INT_FIELDS else np.float64,
+                        count=n)
+            for name in _FIELDS))
+
+    def to_flows(self) -> list[ScheduledFlow]:
+        return list(map(ScheduledFlow,
+                        *(getattr(self, name).tolist() for name in _FIELDS)))
+
+
+_FIELDS = tuple(f.name for f in dataclasses.fields(FlowColumns))
+_INT_FIELDS = _FIELDS[:5]
+
+
 class Schedule:
     """A complete feasible schedule plus derived metrics.
 
@@ -38,13 +76,44 @@ class Schedule:
     skips per-flow object materialization); the legacy oracle path and
     ``engine.schedule_all_cores`` always carry the full ``Assignment``, which
     the theory certificates (``theory.check_lemma2/3``) require.
+
+    The flows (all cores; core-major, priority order within a core) come
+    either as ``ScheduledFlow`` records (``flows=``, the oracle paths) or as
+    ``FlowColumns`` (``columns=``, the flat engine). ``columns()`` gives the
+    arrays either way; ``flows`` gives the records, built from the columns
+    on first access and kept.
     """
 
-    inst: Instance
-    pi: np.ndarray
-    assignment: Assignment | None
-    flows: list[ScheduledFlow]           # all cores
-    ccts: np.ndarray                     # (M,) indexed by ORIGINAL coflow id order
+    def __init__(self, inst: Instance, pi: np.ndarray,
+                 assignment: Assignment | None, *, ccts: np.ndarray,
+                 flows: list[ScheduledFlow] | None = None,
+                 columns: FlowColumns | None = None) -> None:
+        if (flows is None) == (columns is None):
+            raise ValueError("a Schedule takes exactly one of flows, columns")
+        self.inst = inst
+        self.pi = pi
+        self.assignment = assignment
+        self.ccts = ccts            # (M,) indexed by ORIGINAL coflow id order
+        self._flows = flows
+        self._columns = columns
+
+    @property
+    def flows(self) -> list[ScheduledFlow]:
+        if self._flows is None:
+            self._flows = self._columns.to_flows()
+        return self._flows
+
+    def columns(self) -> FlowColumns:
+        """The flows as arrays: the engine's own, or read off the records."""
+        if self._columns is not None:
+            return self._columns
+        return FlowColumns.from_flows(self._flows)
+
+    @property
+    def n_flows(self) -> int:
+        if self._columns is not None:
+            return self._columns.n_flows
+        return len(self._flows)
 
     @property
     def total_weighted_cct(self) -> float:

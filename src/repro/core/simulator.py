@@ -71,20 +71,12 @@ def validate(s: Schedule, releases: np.ndarray | None = None,
     checks (exclusivity, conservation, CCTs, releases) are delay-agnostic.
     """
     inst = s.inst
-    F = len(s.flows)
+    c = s.columns()
+    F = c.n_flows
     if F:
-        core = np.fromiter((f.core for f in s.flows), dtype=np.int64, count=F)
-        fi = np.fromiter((f.i for f in s.flows), dtype=np.int64, count=F)
-        fj = np.fromiter((f.j for f in s.flows), dtype=np.int64, count=F)
-        size = np.fromiter((f.size for f in s.flows), dtype=np.float64, count=F)
-        t_est = np.fromiter((f.t_establish for f in s.flows), dtype=np.float64,
-                            count=F)
-        t_start = np.fromiter((f.t_start for f in s.flows), dtype=np.float64,
-                              count=F)
-        t_comp = np.fromiter((f.t_complete for f in s.flows), dtype=np.float64,
-                             count=F)
-        orig = np.asarray(s.pi, dtype=np.int64)[
-            np.fromiter((f.coflow for f in s.flows), dtype=np.int64, count=F)]
+        core, fi, fj, size = c.core, c.i, c.j, c.size
+        t_est, t_start, t_comp = c.t_establish, c.t_start, c.t_complete
+        orig = np.asarray(s.pi, dtype=np.int64)[c.coflow]
 
         # --- 5. release respect (online schedules) ------------------------
         if releases is not None:

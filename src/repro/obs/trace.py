@@ -34,7 +34,9 @@ its own tracer for the call):
   ``oneshot``              the whole request (``coflows``, ``flows``,
                            ``hit``, ``compiles``: XLA compilations during
                            the call that the persistent cache did not
-                           answer, see :func:`compile_count`)
+                           answer, see :func:`compile_count`;
+                           ``materialized``: ``ScheduledFlow`` records
+                           built during the call, 0 on the columnar path)
   ``oneshot/key``          fabric fingerprint, ``instance_key``, lookup
   ``oneshot/order``        ``order_coflows`` / ``online_orders``
   ``oneshot/extract``      ``extract_flows`` (``flows``)
@@ -47,7 +49,7 @@ its own tracer for the call):
   ``oneshot/event_loop``   ``_times_for_table`` (``events``: heap pops,
                            ``candidates``: pending flows examined at
                            events, see ``engine.LoopCounts``)
-  ``oneshot/schedule``     ``_schedule_from_times``
+  ``oneshot/schedule``     ``_schedule_from_times`` (the flow columns)
   ``oneshot/emit``         ``compile_schedule`` (``segments``)
   ``oneshot/cache``        relabelling and the cache put
 

@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.core.arrays import F8
 from repro.core.batch import ResultTable, run_batch
+from repro.core.circuit_scheduler import records_built
 from repro.core.coflow import Coflow, Instance, OnlineInstance
 from repro.core.effects import effects
 from repro.core.engine import (
@@ -195,6 +196,10 @@ class FabricManager:
         self._c_finalized = self.metrics.counter("service.finalized")
         self._c_ticks = self.metrics.counter("service.ticks")
         self._c_flows = self.metrics.counter("service.flows_committed")
+        # ScheduledFlow records built by one-shot requests: the one-shot
+        # path emits from columns, so this stays 0 unless that regresses
+        self._c_materialized = self.metrics.counter(
+            "service.flows_materialized")
         self._g_depth_max = self.metrics.gauge("service.queue_depth_max")
         self._g_depth_sum = self.metrics.gauge("service.queue_depth_sum")
         # per-tick wall + per-coflow decision latency; the histogram window
@@ -429,20 +434,26 @@ class FabricManager:
 
         Traced as ``oneshot`` and its sub-spans (``repro.obs.trace``): the
         manager's tracer is the process's current tracer for the call, so
-        the engine's free functions record into it.
+        the engine's free functions record into it. ``materialized`` on the
+        span (and the counter ``service.flows_materialized``) counts the
+        ``ScheduledFlow`` records the request built.
         """
         tracer = self._tracer
         prev = set_tracer(tracer)
         try:
             with tracer.span("oneshot") as sp:
                 c0 = compile_count() if sp.live else 0
+                r0 = records_built()
                 program, hit = self._schedule_instance(
                     inst, algorithm, scheduling, seed, backend)
+                materialized = records_built() - r0
+                self._c_materialized.inc(materialized)
                 if sp.live:
                     base = inst.inst if isinstance(inst, OnlineInstance) \
                         else inst
                     sp.set(coflows=base.M, flows=program.n_segments, hit=hit,
-                           compiles=compile_count() - c0)
+                           compiles=compile_count() - c0,
+                           materialized=materialized)
         finally:
             set_tracer(prev)
         return program, hit

@@ -22,10 +22,9 @@ from typing import TYPE_CHECKING, Annotated, Iterator, Sequence
 import numpy as np
 
 from repro.core.arrays import F8, I8
-from repro.core.circuit_scheduler import ScheduledFlow
 from repro.core.coflow import Coflow, Instance
 from repro.core.effects import effects
-from repro.core.scheduler import Schedule
+from repro.core.scheduler import FlowColumns, Schedule
 from repro.obs.trace import current_tracer
 
 if TYPE_CHECKING:
@@ -153,19 +152,13 @@ class CircuitProgram:
         inst = Instance(coflows=coflows, rates=self.rates, delta=self.delta)
         ccts = np.zeros(uniq.size)
         np.maximum.at(ccts, pos, self.t_complete)
-        dl = self.seg_delta()
-        flows = [
-            ScheduledFlow(
-                coflow=int(pos[s]), cid=int(self.cid[s]),
-                i=int(self.ingress[s]), j=int(self.egress[s]),
-                core=int(self.core[s]), size=float(self.size[s]),
-                t_establish=float(self.t_establish[s]),
-                t_start=float(self.t_establish[s]) + float(dl[s]),
-                t_complete=float(self.t_complete[s]))
-            for s in range(self.n_segments)
-        ]
+        columns = FlowColumns(
+            coflow=pos, cid=self.cid, i=self.ingress, j=self.egress,
+            core=self.core, size=self.size, t_establish=self.t_establish,
+            t_start=self.t_establish + self.seg_delta(),
+            t_complete=self.t_complete)
         return Schedule(inst=inst, pi=np.arange(uniq.size), assignment=None,
-                        flows=flows, ccts=ccts)
+                        ccts=ccts, columns=columns)
 
     def drop(self, keys: set) -> "CircuitProgram":
         """Remove the segments whose ``(cid, ingress, egress, core,
@@ -259,20 +252,13 @@ def compile_schedule(s: Schedule, *, index_labels: bool = False) -> CircuitProgr
     Traced as ``oneshot/emit`` (``segments``).
     """
     with current_tracer().span("oneshot/emit") as sp:
-        F = len(s.flows)
+        c = s.columns()
         if sp.live:
-            sp.set(segments=F)
-        if F == 0:
+            sp.set(segments=c.n_flows)
+        if c.n_flows == 0:
             return CircuitProgram.empty(s.inst.rates, s.inst.delta, s.inst.N)
-        get = lambda attr, dt: np.fromiter(
-            (getattr(f, attr) for f in s.flows),
-            dtype=dt, count=F)
-        if index_labels:
-            labels = np.asarray(s.pi, dtype=np.int64)[get("coflow", np.int64)]
-        else:
-            labels = get("cid", np.int64)
+        labels = (np.asarray(s.pi, dtype=np.int64)[c.coflow] if index_labels
+                  else c.cid)
         return _sorted_program(
-            s.inst.rates, s.inst.delta, s.inst.N,
-            get("core", np.int64), get("i", np.int64), get("j", np.int64),
-            labels, get("size", np.float64),
-            get("t_establish", np.float64), get("t_complete", np.float64))
+            s.inst.rates, s.inst.delta, s.inst.N, c.core, c.i, c.j, labels,
+            c.size, c.t_establish, c.t_complete)

@@ -34,7 +34,8 @@ RATES = (10.0, 20.0, 30.0)
 
 #: span -> (parent span, attributes it carries), as in the taxonomy
 TAXONOMY = {
-    "oneshot": (None, {"coflows", "flows", "hit", "compiles"}),
+    "oneshot": (None, {"coflows", "flows", "hit", "compiles",
+                       "materialized"}),
     "oneshot/key": ("oneshot", set()),
     "oneshot/order": ("oneshot", set()),
     "oneshot/extract": ("oneshot", {"flows"}),
