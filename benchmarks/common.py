@@ -82,8 +82,7 @@ def emit_json(name: str, payload, wall_s: float, out_dir: str | None = None,
 
 def run_setting(*, N=16, M=100, rates=(10, 20, 30), delta=8.0, seeds=(0, 1, 2),
                 weight_mode="uniform-int", algorithms=ALGORITHMS,
-                scheduling="work-conserving", check="validate",
-                workers=None) -> dict:
+                check="validate", workers=None) -> dict:
     """Mean normalized weighted CCT (+ tails) over seeds, normalized to OURS.
 
     One ``run_batch`` call covers the whole (seed x algorithm) grid; the
@@ -97,8 +96,7 @@ def run_setting(*, N=16, M=100, rates=(10, 20, 30), delta=8.0, seeds=(0, 1, 2),
         for seed in seeds
     ]
     tab = run_batch(
-        insts, algorithms, seeds=tuple(seeds), pair_seeds=True,
-        schedulings=(scheduling,), check=check,
+        insts, algorithms, seeds=tuple(seeds), pair_seeds=True, check=check,
         workers=DEFAULT_WORKERS if workers is None else workers,
     )
     base_alg = "ours" if "ours" in algorithms else algorithms[0]

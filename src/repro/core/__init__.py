@@ -6,8 +6,6 @@ and trace-driven workload generation.
 from .batch import ResultTable, SweepRow, row_from_ccts, run_batch  # noqa: F401
 from .engine import (  # noqa: F401
     BACKENDS,
-    INCREMENTAL_SCHEDULINGS,
-    SCHEDULINGS,
     FabricState,
     FlowTable,
     TickCommit,

@@ -1,8 +1,8 @@
 """Batched multi-instance sweep API over the vectorized scheduling engine.
 
-``run_batch`` maps a whole parameter grid — instances x algorithms x
-scheduling policies (x seeds) — to per-run ``Schedule`` metrics, optionally
-fanning out across processes. Every run is gated by the differential-testing
+``run_batch`` maps a whole parameter grid — instances x algorithms (x
+seeds) — to per-run ``Schedule`` metrics, optionally fanning out across
+processes. Every run is gated by the differential-testing
 harness: ``check="validate"`` (default) passes each schedule through the
 independent feasibility validator, ``check="oracle"`` additionally replays
 the legacy per-core scheduler and asserts exact agreement, so a sweep can
@@ -29,11 +29,9 @@ import numpy as np
 from repro.obs.clock import now
 
 from .coflow import Instance, OnlineInstance
-from .scheduler import ALGORITHMS, Schedule, tail_quantile
+from .scheduler import ALGORITHMS, SUNFLOW_ALGORITHMS, Schedule, tail_quantile
 
 __all__ = ["SweepRow", "ResultTable", "run_batch", "row_from_ccts"]
-
-_SUNFLOW_ALGS = ("sunflow-core", "rand-sunflow")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,7 +40,7 @@ class SweepRow:
 
     instance: int          # index into the `instances` argument
     algorithm: str
-    scheduling: str        # "sunflow" for the sunflow baselines
+    scheduling: str        # "sunflow" (sunflow baselines), else "work-conserving"
     seed: int
     weighted_cct: float
     total_cct: float
@@ -137,26 +135,23 @@ def _run_one(payload: tuple) -> SweepRow:
 
     if materialize == "metrics":
         t0 = now()
-        ccts, n_flows = run_fast_metrics(inst, alg, seed=seed, scheduling=sched,
+        ccts, n_flows = run_fast_metrics(inst, alg, seed=seed,
                                          backend=backend, releases=rel)
         wall = now() - t0
         return row_from_ccts(idx, alg, sched, seed, inst.weights, ccts,
                              n_flows, wall)
     t0 = now()
     if rel is None:
-        s = run_fast(inst, alg, seed=seed, scheduling=sched, backend=backend)
+        s = run_fast(inst, alg, seed=seed, backend=backend)
     else:
         oinst = OnlineInstance(inst=inst, releases=rel)
-        s = run_fast_online(oinst, alg, seed=seed, scheduling=sched,
-                            backend=backend)
+        s = run_fast_online(oinst, alg, seed=seed, backend=backend)
     wall = now() - t0
     if check == "oracle":
         if rel is None:
-            cross_check(inst, alg, seed=seed, scheduling=sched, fast=s,
-                        backend=backend)
+            cross_check(inst, alg, seed=seed, fast=s, backend=backend)
         else:
-            cross_check_online(oinst, alg, seed=seed, scheduling=sched, fast=s,
-                               backend=backend)
+            cross_check_online(oinst, alg, seed=seed, fast=s, backend=backend)
     elif check == "validate":
         from .simulator import validate
         validate(s, releases=rel)
@@ -199,7 +194,6 @@ def run_batch(
     algorithms: Iterable[str] = ALGORITHMS,
     *,
     seeds: Sequence[int] = (0,),
-    schedulings: Iterable[str] = ("work-conserving",),
     pair_seeds: bool = False,
     check: str = "validate",
     workers: int | None = None,
@@ -209,13 +203,12 @@ def run_batch(
 ) -> ResultTable:
     """Run a whole sweep grid through the batched engine.
 
-    ``instances x algorithms x schedulings x seeds`` is the full grid;
-    with ``pair_seeds=True``, ``seeds`` must align with ``instances`` and
-    seed ``seeds[i]`` is used only for instance ``i`` (the benchmark
-    convention, where the instance-sampling seed doubles as the rand-assign
-    seed). The sunflow baselines ignore ``schedulings`` — they always use
-    their own coflow-at-a-time policy and are run once per (instance, seed)
-    with scheduling recorded as ``"sunflow"``.
+    ``instances x algorithms x seeds`` is the full grid; with
+    ``pair_seeds=True``, ``seeds`` must align with ``instances`` and seed
+    ``seeds[i]`` is used only for instance ``i`` (the benchmark convention,
+    where the instance-sampling seed doubles as the rand-assign seed). Each
+    row records its scheduling: ``"sunflow"`` for the sunflow baselines'
+    coflow-at-a-time policy, ``"work-conserving"`` for the others.
 
     Online grid points: an entry of ``instances`` may be an
     ``OnlineInstance``, and/or ``releases`` may give a per-instance release
@@ -252,7 +245,6 @@ def run_batch(
     from .engine import BACKENDS
 
     algorithms = tuple(algorithms)
-    schedulings = tuple(schedulings)
     seeds = tuple(seeds)
     unknown = set(algorithms) - set(ALGORITHMS)
     if unknown:
@@ -286,13 +278,10 @@ def run_batch(
         inst_seeds = (seeds[idx],) if pair_seeds else seeds
         for seed in inst_seeds:
             for alg in algorithms:
-                if alg in _SUNFLOW_ALGS:
-                    grid.append((idx, inst, rel, alg, "sunflow", seed, check,
-                                 backend, materialize))
-                else:
-                    for sched in schedulings:
-                        grid.append((idx, inst, rel, alg, sched, seed, check,
-                                     backend, materialize))
+                sched = ("sunflow" if alg in SUNFLOW_ALGORITHMS
+                         else "work-conserving")
+                grid.append((idx, inst, rel, alg, sched, seed, check,
+                             backend, materialize))
 
     if backend == "pallas":
         if workers is not None and workers > 1:

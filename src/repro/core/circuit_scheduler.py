@@ -11,7 +11,7 @@ behaviour (SUNFLOW-CORE baseline): coflows are served strictly sequentially on
 the core — no cross-coflow work conservation — with intra-coflow largest-first
 list scheduling, matching Sunflow's non-preemptive single-coflow scheduler.
 (It passes ``guard=True`` to ``_run_list_scheduler`` explicitly, i.e. the
-priority-guarded scan, for the intra-coflow phase.)
+guarded scan, for the intra-coflow phase.)
 
 Time comparisons follow ONE convention, shared with the online path: all
 comparisons are exact float comparisons — a port is free at event ``t`` iff
@@ -37,7 +37,6 @@ __all__ = [
     "records_built",
     "schedule_core_list",
     "schedule_core_sunflow",
-    "schedule_core_reserving",
 ]
 
 
@@ -84,22 +83,21 @@ def _run_list_scheduler(
 ) -> np.ndarray:
     """Core event loop. Flows are given in priority order; returns t_establish.
 
-    ``guard=True`` implements the paper's work-conservation wording literally
-    ("when there are NO higher-priority flows on a port pair, lower-priority
-    flows can be processed"): a pending higher-priority flow *protects* its
-    two ports, so lower-priority flows cannot backfill onto them. Without the
-    guard (guard=False) any feasible flow starts immediately — greedier, but
-    a long low-priority flow can occupy a port a high-priority flow needs
-    next, which is how the Lemma 3 bound gets violated in practice (see
-    tests/test_theory.py::TestReproductionFindings).
+    ``guard=True`` (the sunflow baseline's scan within one coflow) reads the
+    paper's work-conservation wording literally ("when there are NO
+    higher-priority flows on a port pair, lower-priority flows can be
+    processed"): a pending higher-priority flow *protects* its two ports, so
+    lower-priority flows cannot backfill onto them. Without the guard
+    (guard=False, the work-conserving scheduler) any feasible flow starts
+    immediately — greedier, but a long low-priority flow can occupy a port a
+    high-priority flow needs next, which is how the Lemma 3 bound gets
+    violated in practice (see tests/test_theory.py::TestReproductionFindings).
 
-    ``releases`` (per flow, aligned with ``fi``) gates eligibility on arrival
-    times: a flow may establish only at events ``t >= releases[f]``. All
-    comparisons are exact (``release <= t``, ``free <= t`` — see the module
-    docstring); release times are seeded into the event heap so eligibility
-    flips exactly at the release instant. An unreleased flow is invisible to
-    the scheduler: under ``guard=True`` it does NOT protect its ports (the
-    online scheduler cannot know flows that have not arrived).
+    ``releases`` (per flow, aligned with ``fi``; unguarded scan only) gates
+    eligibility on arrival times: a flow may establish only at events
+    ``t >= releases[f]``. All comparisons are exact (``release <= t``,
+    ``free <= t`` — see the module docstring); release times are seeded into
+    the event heap so eligibility flips exactly at the release instant.
     """
     F = len(sizes)
     t_est = np.full(F, -1.0)
@@ -127,7 +125,7 @@ def _run_list_scheduler(
         blocked_out = np.zeros(n_ports, dtype=bool)
         for f in pend:
             if releases is not None and releases[f] > t:
-                continue  # not yet arrived: cannot start, cannot protect
+                continue  # not yet arrived
             i, j = fi[f], fj[f]
             if (free_in[i] <= t and free_out[j] <= t
                     and not blocked_in[i] and not blocked_out[j]):
@@ -153,16 +151,10 @@ def schedule_core_list(
     rate: float,
     delta: float,
     n_ports: int,
-    guard: bool = False,
     releases: np.ndarray | None = None,
 ) -> list[ScheduledFlow]:
     """The paper's work-conserving priority list scheduler for one core
     (Alg. 1 lines 23-31, literal: any flow whose two ports are idle starts).
-
-    ``guard=True`` is the priority-guarded variant (pending higher-priority
-    flows protect their port pairs). Reproduction finding: the guard HURTS —
-    it creates cascading idle-while-blocked states (~2x worse weighted CCT on
-    trace workloads) and still does not restore Lemma 3; see EXPERIMENTS.md.
 
     ``releases`` (per flow, aligned with ``flows``) adds online release
     gating — see ``_run_list_scheduler``.
@@ -170,8 +162,8 @@ def schedule_core_list(
     fi = np.array([af.flow.i for af in flows], dtype=np.int64)
     fj = np.array([af.flow.j for af in flows], dtype=np.int64)
     sizes = np.array([af.flow.size for af in flows], dtype=np.float64)
-    t_est = _run_list_scheduler(fi, fj, sizes, rate, delta, n_ports, guard=guard,
-                                releases=releases)
+    t_est = _run_list_scheduler(fi, fj, sizes, rate, delta, n_ports,
+                                guard=False, releases=releases)
     out = []
     for idx, af in enumerate(flows):
         te = float(t_est[idx])
@@ -191,54 +183,6 @@ def schedule_core_list(
     return out
 
 
-def schedule_core_reserving(
-    flows: list,  # list[AssignedFlow] for one core, in global priority order
-    core: int,
-    rate: float,
-    delta: float,
-    n_ports: int,
-    releases: np.ndarray | None = None,
-) -> list[ScheduledFlow]:
-    """Alternative reading of Alg. 1 lines 23-31: sequential reservation.
-
-    Flows are committed strictly in pi order; each starts at the earliest time
-    both its ports are free given prior reservations, with no backfilling of
-    lower-priority flows into gaps. Kept as a documented variant (see
-    EXPERIMENTS.md reproduction notes): neither this nor the work-conserving
-    policy satisfies Lemma 3 on all adversarial instances, and the two differ
-    measurably on trace workloads.
-
-    ``releases`` (per flow): online variant — flows are committed in the
-    given (arrival) order and each reservation additionally starts no
-    earlier than the flow's release time.
-    """
-    avail_in = np.zeros(n_ports)
-    avail_out = np.zeros(n_ports)
-    out = []
-    for idx, af in enumerate(flows):
-        i, j, d = af.flow.i, af.flow.j, af.flow.size
-        t = float(max(avail_in[i], avail_out[j]))
-        if releases is not None and releases[idx] > t:
-            t = float(releases[idx])
-        tc = t + delta + d / rate
-        avail_in[i] = tc
-        avail_out[j] = tc
-        out.append(
-            ScheduledFlow(
-                coflow=af.flow.coflow,
-                cid=af.flow.cid,
-                i=i,
-                j=j,
-                core=core,
-                size=d,
-                t_establish=t,
-                t_start=t + delta,
-                t_complete=tc,
-            )
-        )
-    return out
-
-
 def schedule_core_sunflow(
     flows: list,  # list[AssignedFlow] for one core, in global priority order
     core: int,
@@ -249,7 +193,7 @@ def schedule_core_sunflow(
     """SUNFLOW-CORE: serve coflows one at a time (barrier between coflows)."""
     out: list[ScheduledFlow] = []
     barrier = 0.0
-    # Group by coflow position, preserving pi order.
+    # Group by coflow position, keeping pi order.
     groups: dict[int, list] = {}
     for af in flows:
         groups.setdefault(af.flow.coflow, []).append(af)

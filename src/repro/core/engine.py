@@ -22,7 +22,7 @@ id, so one merged event loop drives the whole machine:
 The legacy per-core schedulers are kept untouched as the *reference oracle*:
 ``cross_check`` runs both paths and asserts bit-level agreement, and the
 differential-testing harness (tests/test_engine_differential.py) drives
-randomized instances through it for every algorithm x scheduling policy.
+randomized instances through it for every algorithm.
 All completion times are computed with the exact float associativity of the
 legacy code (``(t + delta) + size/rate``) so agreement is exact, not just
 within tolerance.
@@ -48,7 +48,7 @@ from .arrays import F8, I8
 from .coflow import Coflow, Instance, OnlineInstance, extract_flows
 from .effects import effects
 from .ordering import order_coflows, priority_scores
-from .scheduler import FlowColumns, Schedule
+from .scheduler import SUNFLOW_ALGORITHMS, FlowColumns, Schedule
 from repro.obs.trace import NULL_TRACER, Tracer, current_tracer
 
 if TYPE_CHECKING:   # runtime import would cycle: fault.py imports engine
@@ -58,8 +58,6 @@ __all__ = [
     "FlowTable",
     "FabricState",
     "TickCommit",
-    "SCHEDULINGS",
-    "INCREMENTAL_SCHEDULINGS",
     "BACKENDS",
     "build_flow_table",
     "schedule_all_cores",
@@ -71,11 +69,6 @@ __all__ = [
     "cross_check_online",
     "cross_check_incremental",
 ]
-
-#: Intra-core policies understood by the engine. ``sunflow`` is the
-#: coflow-at-a-time policy used by the SUNFLOW-CORE baselines; the other
-#: three mirror ``scheduler.run``'s ``scheduling`` argument.
-SCHEDULINGS = ("work-conserving", "priority-guard", "reserving", "sunflow")
 
 #: Assignment-phase backends. ``numpy`` runs the flat-array re-implementation
 #: of the Python oracles (bit-identical choices); ``pallas`` dispatches the
@@ -130,15 +123,14 @@ class FlowTable:
         return int(self.pos.size)
 
 
-def _resolve_algorithm(algorithm: str, scheduling: str) -> tuple[str, str]:
-    """(assignment policy, effective scheduling) for an algorithm name."""
+def _resolve_algorithm(algorithm: str) -> tuple[str, bool]:
+    """(assignment policy, whether it is a sunflow baseline) for an
+    algorithm name."""
     if algorithm not in _POLICY_OF:
         from .scheduler import ALGORITHMS
         raise ValueError(
             f"unknown algorithm {algorithm!r}; one of {sorted(ALGORITHMS)}")
-    if algorithm in ("sunflow-core", "rand-sunflow"):
-        scheduling = "sunflow"
-    return _POLICY_OF[algorithm], scheduling
+    return _POLICY_OF[algorithm], algorithm in SUNFLOW_ALGORITHMS
 
 
 def _pallas_choices(inst: Instance, flows: tuple[np.ndarray, ...]) -> np.ndarray:
@@ -192,7 +184,7 @@ def build_flow_table(
         if delta_k.shape != (inst.K,):
             raise ValueError(
                 f"delta_k must have shape ({inst.K},), got {delta_k.shape}")
-    policy, _ = _resolve_algorithm(algorithm, "")
+    policy, _ = _resolve_algorithm(algorithm)
     tracer = current_tracer()
     with tracer.span("oneshot/extract") as sp:
         flows = extract_flows(inst, pi)
@@ -286,8 +278,8 @@ class LoopCounts:
     #: pending flows examined at events after the first: by the
     #: work-conserving loop's scans of the freed resources' pending lists
     #: (each entry once an event and list) and of the flows released then;
-    #: with ``guard=True``, the pending flows of the cores active at each
-    #: event
+    #: by the sunflow baseline's guarded scan (:func:`_guarded_scan`), the
+    #: pending flows of the cores active at each event
     candidates: int = 0
 
 
@@ -295,32 +287,26 @@ def _event_loop(
     rin: np.ndarray,       # (F,) int64 ingress resource ids (core*N + i)
     rout: np.ndarray,      # (F,) int64 egress resource ids (core*N + j)
     srv: np.ndarray,       # (F,) float64 service times size/rate[core]
-    core: np.ndarray,      # (F,) int64
     delta: float,
     n_res: int,
-    n_ports: int,
     t0: float = 0.0,
-    guard: bool = False,
     release: np.ndarray | None = None,
     free_in0: np.ndarray | None = None,
     free_out0: np.ndarray | None = None,
     counts: LoopCounts | None = None,
 ) -> np.ndarray:
-    """Vectorized merged event loop; flows are in priority order per core.
+    """Vectorized merged work-conserving event loop; flows are in priority
+    order per core.
 
     Returns t_establish per flow. Exactly reproduces the legacy sequential
     scan: at each event, the started set is {flows whose two resources are
-    free and which are the first pending user of both} — iterated to a fixed
-    point for guard=False (at its first event; later events reach the same
-    set by the per-resource picks below), single-pass for guard=True (where
-    a pending higher-priority flow makes both its resources unavailable
-    whether or not it starts, so "first on both" is already the full
-    answer).
+    free and which are the first pending user of both}, iterated to a fixed
+    point (at the first event; later events reach the same set by the
+    per-resource picks below).
 
-    Work-conserving fast path: after each event, every pending flow has at
-    least one busy resource (else it would have started), so a flow can
-    only become startable at an event where one of its resources completes
-    *exactly then*. The first event runs the fixed point over every flow;
+    After each event, every pending flow has at least one busy resource
+    (else it would have started), so a flow can only become startable at an
+    event where one of its resources completes *exactly then*. The first event runs the fixed point over every flow;
     later ones pick their starts per freed resource (:func:`_pick_starts`).
     Each resource keeps the list of its pending flows in priority order (a
     flow leaves both its lists when it starts). For each resource freed at
@@ -342,9 +328,7 @@ def _event_loop(
     event heap, extending the invariant above: a pending flow either has a
     busy resource or an unreached release, so the flows that can start at
     an event lie on just-freed resources or were released exactly then (the
-    first of those whose resources are both free is one more pick). An
-    unreleased flow never protects its ports under ``guard=True`` (the
-    online scheduler cannot know flows that have not arrived).
+    first of those whose resources are both free is one more pick).
 
     ``free_in0``/``free_out0`` (per resource, both or neither) seed the port
     availability horizons from circuits already *committed* by earlier
@@ -393,51 +377,6 @@ def _event_loop(
     # what is left in the heap at the end
     n_pushed = len(events) + F
     n_cand = 0
-
-    if guard:
-        pending = np.arange(F)
-        first_event = True
-        while remaining:
-            if first_event:
-                pend = pending
-                first_event = False
-            else:
-                # Only cores with a completion (or a release) at t can
-                # start flows now.
-                act = np.zeros(n_res // n_ports, dtype=bool)
-                act[np.nonzero(free_in == t)[0] // n_ports] = True  # reprolint: disable=float-eq -- exact-float convention: t was copied verbatim from free_in (circuit_scheduler docstring)
-                act[np.nonzero(free_out == t)[0] // n_ports] = True  # reprolint: disable=float-eq -- exact-float convention: t was copied verbatim from free_out
-                if release is not None:
-                    act[core[pending[release[pending] == t]]] = True  # reprolint: disable=float-eq -- exact-float convention: event times are copied release values, never arithmetic
-                pend = pending[act[core[pending]]]
-                n_cand += pend.size
-            if release is not None and pend.size:
-                pend = pend[release[pend] <= t]
-            if pend.size:
-                ri, rj = rin[pend], rout[pend]
-                feas = (
-                    (free_in[ri] <= t) & (free_out[rj] <= t)
-                    & _first_occurrence(ri, scratch) & _first_occurrence(rj, scratch)
-                )
-                start = pend[feas]
-                if start.size:
-                    tc = (t + (delta if d_vec is None else d_vec[start])) \
-                        + srv[start]
-                    free_in[rin[start]] = tc
-                    free_out[rout[start]] = tc
-                    t_est[start] = t
-                    done[start] = True
-                    remaining -= start.size
-                    for v in tc.tolist():
-                        heapq.heappush(events, v)
-                    pending = pending[~done[pending]]
-                    if not remaining:
-                        break
-            t = _pop_next_event(events, t)
-        if counts is not None:
-            counts.events += n_pushed - len(events)
-            counts.candidates += n_cand
-        return t_est
 
     # The first event: every (released) flow is a candidate.
     cand = np.arange(F)
@@ -576,39 +515,75 @@ def _pick_starts(
     return np.array(te), n_cand
 
 
-def _reserving_times(
-    rin: np.ndarray, rout: np.ndarray, srv: np.ndarray, delta: float,
-    n_res: int, release: np.ndarray | None = None,
-    avail_in: np.ndarray | None = None,
-    avail_out: np.ndarray | None = None,
+def _guarded_scan(
+    rin: np.ndarray,       # (F,) int64 ingress resource ids (core*N + i)
+    rout: np.ndarray,      # (F,) int64 egress resource ids (core*N + j)
+    srv: np.ndarray,       # (F,) float64 service times size/rate[core]
+    core: np.ndarray,      # (F,) int64
+    delta: float,
+    n_res: int,
+    n_ports: int,
+    t0: float = 0.0,
+    counts: LoopCounts | None = None,
 ) -> np.ndarray:
-    """Strict in-order reservation (no backfill) over merged resources.
+    """The guarded list scan of the sunflow baseline, from ``t0`` with
+    every resource free then; flows are in priority order per core.
 
-    ``release`` (per flow) is the online variant: flows are given in
-    commitment (arrival) order and each reservation starts no earlier than
-    its release.
+    Returns t_establish per flow. At each event a pending flow that cannot
+    start still blocks both its resources for the rest of that event, so
+    the started set is, in one pass, {flows whose two resources are free
+    and which are the first pending user of both}. After the first event
+    only the cores with a completion at ``t`` are scanned: nothing else
+    changed on the other cores since their last scan.
 
-    ``avail_in``/``avail_out`` (both or neither) carry reservation horizons
-    across service ticks; they are MUTATED in place, which is exactly the
-    incremental contract — a reservation, once made, never changes, so the
-    arrays double as the committed-circuit state.
-
-    ``delta`` may be a per-flow ``(F,)`` array (drifted per-core delays).
+    ``counts``, when given, has this call's heap pops and candidates (the
+    pending flows of the scanned cores at events after the first) added to
+    it once, at the end (see :class:`LoopCounts`).
     """
-    d_vec = None if np.ndim(delta) == 0 else np.asarray(delta, dtype=np.float64)
-    if avail_in is None:
-        avail_in = np.zeros(n_res)
-        avail_out = np.zeros(n_res)
-    t_est = np.empty(rin.size)
-    for f in range(rin.size):
-        i, j = rin[f], rout[f]
-        t = avail_in[i] if avail_in[i] >= avail_out[j] else avail_out[j]
-        if release is not None and release[f] > t:
-            t = release[f]
-        tc = t + (delta if d_vec is None else d_vec[f]) + srv[f]
-        avail_in[i] = tc
-        avail_out[j] = tc
-        t_est[f] = t
+    F = rin.size
+    t_est = np.full(F, -1.0)
+    if F == 0:
+        return t_est
+    free_in = np.full(n_res, t0)
+    free_out = np.full(n_res, t0)
+    done = np.zeros(F, dtype=bool)
+    scratch = np.empty(n_res, dtype=np.int64)
+    events: list = []  # heap of future completion times
+    remaining = F
+    t = t0
+    n_cand = 0
+    pending = pend = np.arange(F)
+    while True:
+        ri, rj = rin[pend], rout[pend]
+        feas = (
+            (free_in[ri] <= t) & (free_out[rj] <= t)
+            & _first_occurrence(ri, scratch) & _first_occurrence(rj, scratch)
+        )
+        start = pend[feas]
+        if start.size:
+            tc = (t + delta) + srv[start]
+            free_in[rin[start]] = tc
+            free_out[rout[start]] = tc
+            t_est[start] = t
+            done[start] = True
+            remaining -= start.size
+            for v in tc.tolist():
+                heapq.heappush(events, v)
+            pending = pending[~done[pending]]
+            if not remaining:
+                break
+        t = _pop_next_event(events, t)
+        # Only cores with a completion at t can start flows now.
+        act = np.zeros(n_res // n_ports, dtype=bool)
+        act[np.nonzero(free_in == t)[0] // n_ports] = True  # reprolint: disable=float-eq -- exact-float convention: t was copied verbatim from free_in (circuit_scheduler docstring)
+        act[np.nonzero(free_out == t)[0] // n_ports] = True  # reprolint: disable=float-eq -- exact-float convention: t was copied verbatim from free_out
+        pend = pending[act[core[pending]]]
+        n_cand += pend.size
+    if counts is not None:
+        # every flow pushes one completion: the pops are F less what is
+        # left in the heap
+        counts.events += F - len(events)
+        counts.candidates += n_cand
     return t_est
 
 
@@ -629,7 +604,7 @@ def _sunflow_times(
     one coflow scheduled largest-first.
 
     The legacy ``schedule_core_sunflow`` runs ``_run_list_scheduler`` with the
-    priority-guarded scan — reproduced here with ``guard=True``.
+    guarded scan — reproduced here by :func:`_guarded_scan`.
 
     ``release``/``prio`` (per flow; all flows of a coflow share both) select
     the online variant: whenever the core frees, the *arrived* unserved
@@ -673,10 +648,9 @@ def _sunflow_times(
             grp = on_k[table.pos[on_k] == pos]
             order = np.lexsort((table.fj[grp], table.fi[grp], -table.size[grp]))
             grp = grp[order]
-            te = _event_loop(
+            te = _guarded_scan(
                 rin[grp], rout[grp], srv[grp], table.core[grp], dk,
-                n_res=K * n_ports, n_ports=n_ports, t0=barrier, guard=True,
-                counts=counts,
+                n_res=K * n_ports, n_ports=n_ports, t0=barrier, counts=counts,
             )
             t_est[grp] = te
             barrier = max(barrier, float(((te + dk) + srv[grp]).max()))
@@ -688,26 +662,29 @@ def _times_for_table(
     inst: Instance,
     pi: np.ndarray,
     table: FlowTable,
-    scheduling: str = "work-conserving",
+    *,
+    sunflow: bool = False,
     releases: Annotated[F8, "M"] | None = None,
     delta_k: Annotated[F8, "K"] | None = None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Scheduling phase over a flat ``FlowTable``: returns (t_est, srv).
 
+    The work-conserving list scheduler (Alg. 1 lines 23-31), or with
+    ``sunflow`` the coflow-at-a-time SUNFLOW-CORE baseline.
+
     ``releases`` (indexed by ORIGINAL coflow id, like
     ``OnlineInstance.releases``) switches on the online model: scheduling
     priority becomes the WSPT rank of each coflow (``online.online_orders``),
-    eligibility is release-gated in the merged event loop, and the sunflow /
-    reserving policies use their online variants. ``releases=None`` is the
-    offline path.
+    eligibility is release-gated in the merged event loop, and sunflow uses
+    its online variant. ``releases=None`` is the offline path.
 
     ``delta_k`` (per-core drifted delays; fault model ``DeltaDrift``)
     replaces the uniform ``inst.delta`` with ``delta_k[core]`` per flow.
     ``None`` (or an all-nominal vector, which callers should normalize to
     ``None``) computes the exact pre-drift floats.
 
-    Traced as ``oneshot/event_loop``, with the event loops' ``events`` and
-    ``candidates`` (:class:`LoopCounts`) unless the policy is reserving.
+    Traced as ``oneshot/event_loop``, with the loops' ``events`` and
+    ``candidates`` (:class:`LoopCounts`).
     """
     with current_tracer().span("oneshot/event_loop") as sp:
         counts = LoopCounts() if sp.live else None
@@ -717,21 +694,12 @@ def _times_for_table(
         srv = table.size / inst.rates[table.core]
         dl = inst.delta if delta_k is None \
             else np.asarray(delta_k, dtype=np.float64)[table.core]
-        if scheduling not in SCHEDULINGS:
-            raise ValueError(
-                f"unknown scheduling {scheduling!r}; one of {SCHEDULINGS}")
         if releases is None:
-            if scheduling == "work-conserving":
-                t_est = _event_loop(rin, rout, srv, table.core, dl, K * N, N,
-                                    counts=counts)
-            elif scheduling == "priority-guard":
-                t_est = _event_loop(rin, rout, srv, table.core, dl, K * N, N,
-                                    guard=True, counts=counts)
-            elif scheduling == "reserving":
-                t_est = _reserving_times(rin, rout, srv, dl, K * N)
-            elif scheduling == "sunflow":
+            if sunflow:
                 t_est = _sunflow_times(table, rin, rout, srv, inst.delta, N, K,
                                        delta_k=delta_k, counts=counts)
+            else:
+                t_est = _event_loop(rin, rout, srv, dl, K * N, counts=counts)
         else:
             from .online import online_orders
 
@@ -740,26 +708,21 @@ def _times_for_table(
             rel_f = rel_orig[orig]
             _, prio_rank = online_orders(inst, rel_orig)
             prio_f = prio_rank[orig]
-            if scheduling in ("work-conserving", "priority-guard"):
+            if sunflow:
+                t_est = _sunflow_times(table, rin, rout, srv, inst.delta, N, K,
+                                       release=rel_f, prio=prio_f,
+                                       delta_k=delta_k, counts=counts)
+            else:
                 # The event loop wants flows in scheduling-priority order: WSPT
                 # coflow rank, then the intra-coflow assignment order (stable).
                 perm = np.argsort(prio_f, kind="stable")
                 te = _event_loop(
-                    rin[perm], rout[perm], srv[perm], table.core[perm],
-                    dl if delta_k is None else dl[perm], K * N, N,
-                    guard=(scheduling == "priority-guard"),
+                    rin[perm], rout[perm], srv[perm],
+                    dl if delta_k is None else dl[perm], K * N,
                     release=rel_f[perm], counts=counts)
                 t_est = np.empty_like(te)
                 t_est[perm] = te
-            elif scheduling == "reserving":
-                # commitment in arrival order == the FlowTable's native order
-                t_est = _reserving_times(rin, rout, srv, dl, K * N,
-                                         release=rel_f)
-            elif scheduling == "sunflow":
-                t_est = _sunflow_times(table, rin, rout, srv, inst.delta, N, K,
-                                       release=rel_f, prio=prio_f,
-                                       delta_k=delta_k, counts=counts)
-        if counts is not None and scheduling != "reserving":
+        if counts is not None:
             sp.set(events=counts.events, candidates=counts.candidates)
     return t_est, srv
 
@@ -815,11 +778,11 @@ def schedule_all_cores(
     inst: Instance,
     pi: Annotated[I8, "M"],
     assignment: Assignment,
-    scheduling: str = "work-conserving",
     *,
     releases: Annotated[F8, "M"] | None = None,
 ) -> Schedule:
-    """Schedule every assigned flow on all K cores in one vectorized call.
+    """Schedule every assigned flow on all K cores in one vectorized call,
+    with the work-conserving list scheduler.
 
     Drop-in replacement for ``scheduler._schedule_from_assignment``; produces
     identical ``Schedule`` contents (flows in core-major priority order, same
@@ -829,7 +792,7 @@ def schedule_all_cores(
     a ``FlowTable`` built by ``build_flow_table``.
     """
     table = FlowTable.from_assignment(assignment)
-    t_est, srv = _times_for_table(inst, pi, table, scheduling, releases)
+    t_est, srv = _times_for_table(inst, pi, table, releases=releases)
     return _schedule_from_times(inst, pi, assignment, table, t_est, srv)
 
 
@@ -857,7 +820,6 @@ def run_fast(
     algorithm: str = "ours",
     *,
     seed: int = 0,
-    scheduling: str = "work-conserving",
     backend: str = "numpy",
     delta_k: Annotated[F8, "K"] | None = None,
     locality: float = 0.0,
@@ -885,10 +847,10 @@ def run_fast(
     delta_k = _normalize_delta_k(inst, delta_k)
     with current_tracer().span("oneshot/order"):
         pi = order_coflows(inst)
-    _, scheduling = _resolve_algorithm(algorithm, scheduling)
+    _, sunflow = _resolve_algorithm(algorithm)
     table = build_flow_table(inst, pi, algorithm, seed=seed, backend=backend,
                              delta_k=delta_k, locality=locality)
-    t_est, srv = _times_for_table(inst, pi, table, scheduling,
+    t_est, srv = _times_for_table(inst, pi, table, sunflow=sunflow,
                                   delta_k=delta_k)
     dl_f = None if delta_k is None else delta_k[table.core]
     return _schedule_from_times(inst, pi, None, table, t_est, srv, dl_f)
@@ -900,7 +862,6 @@ def run_fast_metrics(
     algorithm: str = "ours",
     *,
     seed: int = 0,
-    scheduling: str = "work-conserving",
     backend: str = "numpy",
     releases: Annotated[F8, "M"] | None = None,
     delta_k: Annotated[F8, "K"] | None = None,
@@ -924,11 +885,11 @@ def run_fast_metrics(
             releases = np.asarray(releases, dtype=np.float64)
             pi, _ = online_orders(inst, releases)
     delta_k = _normalize_delta_k(inst, delta_k)
-    _, scheduling = _resolve_algorithm(algorithm, scheduling)
+    _, sunflow = _resolve_algorithm(algorithm)
     table = build_flow_table(inst, pi, algorithm, seed=seed, backend=backend,
                              delta_k=delta_k, locality=locality)
-    t_est, srv = _times_for_table(inst, pi, table, scheduling, releases,
-                                  delta_k=delta_k)
+    t_est, srv = _times_for_table(inst, pi, table, sunflow=sunflow,
+                                  releases=releases, delta_k=delta_k)
     dl_f = None if delta_k is None else delta_k[table.core]
     return _ccts_from_times(inst, pi, table, t_est, srv, dl_f), table.n_flows
 
@@ -939,7 +900,6 @@ def run_fast_online(
     algorithm: str = "ours",
     *,
     seed: int = 0,
-    scheduling: str = "work-conserving",
     backend: str = "numpy",
     delta_k: Annotated[F8, "K"] | None = None,
     locality: float = 0.0,
@@ -963,11 +923,11 @@ def run_fast_online(
     delta_k = _normalize_delta_k(inst, delta_k)
     with current_tracer().span("oneshot/order"):
         arrival, _ = online_orders(inst, rel)
-    _, scheduling = _resolve_algorithm(algorithm, scheduling)
+    _, sunflow = _resolve_algorithm(algorithm)
     table = build_flow_table(inst, arrival, algorithm, seed=seed,
                              backend=backend, delta_k=delta_k,
                              locality=locality)
-    t_est, srv = _times_for_table(inst, arrival, table, scheduling,
+    t_est, srv = _times_for_table(inst, arrival, table, sunflow=sunflow,
                                   releases=rel, delta_k=delta_k)
     dl_f = None if delta_k is None else delta_k[table.core]
     return _schedule_from_times(inst, arrival, None, table, t_est, srv, dl_f)
@@ -986,17 +946,11 @@ def run_fast_online(
 # committed at tick time T iff its establishment time is <= T. Release
 # gating is the exact comparison ``release <= t``, and every coflow admitted
 # after tick T must have release > T, so no future arrival can participate
-# in (or, under ``priority-guard``, protect ports at) any event at or before
-# T — the committed prefix of the schedule is final. Everything later stays
-# tentative and is re-derived next tick with the newly arrived competitors,
-# which is exactly what the full replay's event loop would do.
+# in any event at or before T — the committed prefix of the schedule is
+# final. Everything later stays tentative and is re-derived next tick with
+# the newly arrived competitors, which is exactly what the full replay's
+# event loop would do.
 # --------------------------------------------------------------------------
-
-#: Intra-core policies the incremental path supports. The sunflow baselines
-#: pick the next coflow at core-free time — a decision that arrivals *after*
-#: the current tick can overturn (the pick may happen arbitrarily far in the
-#: future), so they cannot commit tick-by-tick and require full replay.
-INCREMENTAL_SCHEDULINGS = ("work-conserving", "priority-guard", "reserving")
 
 _PEND_FIELDS = (
     ("gid", np.int64), ("cid", np.int64), ("fi", np.int64), ("fj", np.int64),
@@ -1227,7 +1181,7 @@ class TickCommit:
     unfinalized: tuple = ()  # gids whose final CCT was retracted this tick
     #: resource-sharing components in this tick's pending set, and how many
     #: of them the tick actually re-scheduled (delta-scheduling telemetry;
-    #: both 0 when delta-scheduling is off, reserving, or nothing pends)
+    #: both 0 when delta-scheduling is off or nothing pends)
     components_total: int = 0
     components_touched: int = 0
 
@@ -1258,7 +1212,6 @@ class FabricState:
         delta: float,
         N: int,
         algorithm: str = "ours",
-        scheduling: str = "work-conserving",
         seed: int = 0,
         faults: "FaultInjector | None" = None,
         track_commits: bool | None = None,
@@ -1267,15 +1220,16 @@ class FabricState:
         tracer: Tracer | None = None,
         locality: float = 0.0,
     ) -> None:
-        policy, scheduling = _resolve_algorithm(algorithm, scheduling)
-        if scheduling not in INCREMENTAL_SCHEDULINGS:
+        policy, sunflow = _resolve_algorithm(algorithm)
+        if sunflow:
+            # the sunflow baselines pick the next coflow at core-free time,
+            # a decision that arrivals after the current tick can overturn
+            # (the pick may happen arbitrarily far in the future)
             raise ValueError(
-                f"scheduling {scheduling!r} (algorithm {algorithm!r}) is "
-                f"benchmark-only: the sunflow pick-next-at-core-free rule "
-                f"cannot commit tick-by-tick and requires a full "
-                f"run_fast_online replay (serve it via run_fast / "
-                f"run_fast_online / run_batch); incremental scheduling "
-                f"supports {INCREMENTAL_SCHEDULINGS}")
+                f"algorithm {algorithm!r} is benchmark-only: the sunflow "
+                f"pick-next-at-core-free rule cannot commit tick-by-tick and "
+                f"requires a full run_fast_online replay (serve it via "
+                f"run_fast / run_fast_online / run_batch)")
         self.rates = np.asarray(rates, dtype=np.float64)
         if self.rates.ndim != 1 or (self.rates <= 0).any():
             raise ValueError("rates must be a 1-D positive vector")
@@ -1284,7 +1238,6 @@ class FabricState:
         self.K = int(self.rates.shape[0])
         self.R = float(self.rates.sum())
         self.algorithm = algorithm
-        self.scheduling = scheduling
         #: phase tracer (repro.obs): purely observational — nothing the
         #: engine computes ever reads it, so NULL_TRACER (the default) and
         #: a recording tracer yield bit-identical schedules
@@ -1326,11 +1279,9 @@ class FabricState:
         #: bit-identical commits
         self._fault_scoped_tent = True
         #: incremental component index maintained across ticks/faults; None
-        #: when delta-scheduling is off or reserving commits everything
-        #: immediately (no tentative rows to splice)
+        #: when delta-scheduling is off
         self._cindex: ComponentIndex | None = (
-            ComponentIndex(n_res)
-            if delta_schedule and scheduling != "reserving" else None)
+            ComponentIndex(n_res) if delta_schedule else None)
         #: delta-scheduling effectiveness counters (rows spliced from the
         #: cache vs rows re-run through the event loop, cumulative)
         self.tent_reused = 0
@@ -1469,9 +1420,9 @@ class FabricState:
         watermark can never be aborted again — drop them.
 
         Dropping is also invisible to scheduling: a GCed ``t_comp`` is
-        ``<= gc_floor <= t_now``, and every future event-loop seed /
-        reservation start is ``>= t_now`` (``max`` semantics make values at
-        or below ``t0`` equivalent), so horizon rebuilds after later faults
+        ``<= gc_floor <= t_now``, and every future event-loop seed is
+        ``>= t_now`` (``max`` semantics make values at or below ``t0``
+        equivalent), so horizon rebuilds after later faults
         compute the same floats with or without the dropped rows. The only
         value they still feed — a re-opened coflow's running CCT — is kept
         exact through the per-gid ``_gc_cct`` max.
@@ -1872,117 +1823,101 @@ class FabricState:
         # DeltaDrift moved some core off the nominal delta
         dl_f = None if not self._drifted else self.delta_k[pend["core"]]
         comp_total = comp_touched = 0
-        if self.scheduling == "reserving":
-            # Reservations commit immediately in arrival order and never
-            # move, so the horizon arrays ARE the reservation state.
-            with self._tracer.span("tick/event_loop") as sp_ev:
-                t_est = _reserving_times(
-                    rin, rout, pend["srv"],
-                    self.delta if dl_f is None else dl_f, n_res,
-                    release=pend["rel"], avail_in=self.free_in,
-                    avail_out=self.free_out)
-                if sp_ev.live:
-                    sp_ev.set(rows=int(t_est.size), reserving=True)
-            commit = np.ones(t_est.size, dtype=bool)
-        else:
-            # Delta-scheduling: tentative times are stable across ticks
-            # unless new competitors share a resource component (the same
-            # invariant behind commit finality — an event at or before the
-            # previous tick can't be changed by later arrivals; an event
-            # after it can only be changed by flows in the same component).
-            # So the cached tentative times of untouched components are
-            # spliced, and only the touched rows re-run the event loop.
-            F = rin.size
-            with self._tracer.span("tick/splice") as sp_spl:
-                t_est = np.empty(F)
-                # ONE component query per tick: the incremental index
-                # answers both the touched-row mask and the telemetry the
-                # splice used to derive from two from-scratch union-finds
-                # (_touched_rows + _resource_components, the oracle pair
-                # the differential suites still pin this against)
-                roots = (self._cindex.labels(rin)
-                         if self.delta_schedule and F else None)
-                n_invalid = 0
-                if (self.delta_schedule and self._tent is not None
-                        and self._tent.size == n_old and n_old):
-                    t_est[:n_old] = self._tent
-                    # seeds = new arrivals + rows a fault invalidated; the
-                    # dirty set is every row sharing a component with one
-                    seed = np.zeros(F, dtype=bool)
-                    seed[n_old:] = True
-                    if self._tent_valid is not None:
-                        invalid = ~self._tent_valid
-                        n_invalid = int(invalid.sum())
-                        seed[:n_old] |= invalid
-                    touched = (np.unique(roots[seed]) if seed.any()
-                               else roots[:0])
-                    dirty = (np.isin(roots, touched) if touched.size
-                             else np.zeros(F, dtype=bool))
+        # Delta-scheduling: tentative times are stable across ticks
+        # unless new competitors share a resource component (the same
+        # invariant behind commit finality — an event at or before the
+        # previous tick can't be changed by later arrivals; an event
+        # after it can only be changed by flows in the same component).
+        # So the cached tentative times of untouched components are
+        # spliced, and only the touched rows re-run the event loop.
+        F = rin.size
+        with self._tracer.span("tick/splice") as sp_spl:
+            t_est = np.empty(F)
+            # ONE component query per tick: the incremental index
+            # answers both the touched-row mask and the telemetry the
+            # splice used to derive from two from-scratch union-finds
+            # (_touched_rows + _resource_components, the oracle pair
+            # the differential suites still pin this against)
+            roots = (self._cindex.labels(rin)
+                     if self.delta_schedule and F else None)
+            n_invalid = 0
+            if (self.delta_schedule and self._tent is not None
+                    and self._tent.size == n_old and n_old):
+                t_est[:n_old] = self._tent
+                # seeds = new arrivals + rows a fault invalidated; the
+                # dirty set is every row sharing a component with one
+                seed = np.zeros(F, dtype=bool)
+                seed[n_old:] = True
+                if self._tent_valid is not None:
+                    invalid = ~self._tent_valid
+                    n_invalid = int(invalid.sum())
+                    seed[:n_old] |= invalid
+                touched = (np.unique(roots[seed]) if seed.any()
+                           else roots[:0])
+                dirty = (np.isin(roots, touched) if touched.size
+                         else np.zeros(F, dtype=bool))
+            else:
+                dirty = np.ones(F, dtype=bool)
+                touched = None
+            if roots is not None:
+                uniq, cnts = np.unique(roots, return_counts=True)
+                comp_total = int(uniq.size)
+                if touched is None:
+                    comp_touched = comp_total
+                    reused_cnts = cnts[:0]
+                elif touched.size:
+                    comp_touched = int(touched.size)
+                    reused_cnts = cnts[~np.isin(uniq, touched)]
                 else:
-                    dirty = np.ones(F, dtype=bool)
-                    touched = None
-                if roots is not None:
-                    uniq, cnts = np.unique(roots, return_counts=True)
-                    comp_total = int(uniq.size)
-                    if touched is None:
-                        comp_touched = comp_total
-                        reused_cnts = cnts[:0]
-                    elif touched.size:
-                        comp_touched = int(touched.size)
-                        reused_cnts = cnts[~np.isin(uniq, touched)]
-                    else:
-                        comp_touched = 0
-                        reused_cnts = cnts
-                    hist = self.component_size_hist
-                    for s_, n_ in zip(*np.unique(cnts, return_counts=True)):
+                    comp_touched = 0
+                    reused_cnts = cnts
+                hist = self.component_size_hist
+                for s_, n_ in zip(*np.unique(cnts, return_counts=True)):
+                    s_ = int(s_)
+                    hist[s_] = hist.get(s_, 0) + int(n_)
+                if reused_cnts.size:
+                    hist = self.component_reused_hist
+                    for s_, n_ in zip(*np.unique(reused_cnts,
+                                                 return_counts=True)):
                         s_ = int(s_)
                         hist[s_] = hist.get(s_, 0) + int(n_)
-                    if reused_cnts.size:
-                        hist = self.component_reused_hist
-                        for s_, n_ in zip(*np.unique(reused_cnts,
-                                                     return_counts=True)):
-                            s_ = int(s_)
-                            hist[s_] = hist.get(s_, 0) + int(n_)
-                sub = np.nonzero(dirty)[0]
-                self.tent_reused += int(F - sub.size)
-                self.tent_recomputed += int(sub.size)
-                if sp_spl.live:
-                    sp_spl.set(reused=int(F - sub.size),
-                               recomputed=int(sub.size),
-                               invalidated=n_invalid,
-                               components_total=comp_total,
-                               components_touched=comp_touched)
-            if sub.size:
-                # Priority order: WSPT score desc, admission index,
-                # intra-coflow extraction order — the global arrival
-                # pipeline's flow order restricted to the (touched) pending
-                # set; a component's restriction equals the global order's
-                # restriction because components share no resources.
-                with self._tracer.span("tick/event_loop") as sp_ev:
-                    counts = LoopCounts() if sp_ev.live else None
-                    perm = np.lexsort((pend["intra"][sub], pend["gid"][sub],
-                                       -pend["score"][sub]))
-                    s = sub[perm]
-                    te = _event_loop(
-                        rin[s], rout[s], pend["srv"][s], pend["core"][s],
-                        self.delta if dl_f is None else dl_f[s], n_res,
-                        self.N, t0=t_prev,
-                        guard=(self.scheduling == "priority-guard"),
-                        release=pend["rel"][s],
-                        free_in0=self.free_in, free_out0=self.free_out,
-                        counts=counts)
-                    t_est[s] = te
-                    if counts is not None:
-                        sp_ev.set(rows=int(sub.size), events=counts.events,
-                                  candidates=counts.candidates)
-            commit = t_est <= t_now
+            sub = np.nonzero(dirty)[0]
+            self.tent_reused += int(F - sub.size)
+            self.tent_recomputed += int(sub.size)
+            if sp_spl.live:
+                sp_spl.set(reused=int(F - sub.size),
+                           recomputed=int(sub.size),
+                           invalidated=n_invalid,
+                           components_total=comp_total,
+                           components_touched=comp_touched)
+        if sub.size:
+            # Priority order: WSPT score desc, admission index,
+            # intra-coflow extraction order — the global arrival
+            # pipeline's flow order restricted to the (touched) pending
+            # set; a component's restriction equals the global order's
+            # restriction because components share no resources.
+            with self._tracer.span("tick/event_loop") as sp_ev:
+                counts = LoopCounts() if sp_ev.live else None
+                perm = np.lexsort((pend["intra"][sub], pend["gid"][sub],
+                                   -pend["score"][sub]))
+                s = sub[perm]
+                te = _event_loop(
+                    rin[s], rout[s], pend["srv"][s],
+                    self.delta if dl_f is None else dl_f[s], n_res,
+                    t0=t_prev, release=pend["rel"][s],
+                    free_in0=self.free_in, free_out0=self.free_out,
+                    counts=counts)
+                t_est[s] = te
+                if counts is not None:
+                    sp_ev.set(rows=int(sub.size), events=counts.events,
+                              candidates=counts.candidates)
+        commit = t_est <= t_now
         if dl_f is None:
             tc = (t_est[commit] + self.delta) + pend["srv"][commit]
         else:
             tc = (t_est[commit] + dl_f[commit]) + pend["srv"][commit]
-        if self.scheduling != "reserving":
-            np.maximum.at(self.free_in, rin[commit], tc)
-            np.maximum.at(self.free_out, rout[commit], tc)
+        np.maximum.at(self.free_in, rin[commit], tc)
+        np.maximum.at(self.free_out, rout[commit], tc)
         if self.track_commits:
             newc = {name: pend[name][commit] for name, _dt in _PEND_FIELDS}
             newc["t_est"] = t_est[commit]
@@ -2024,14 +1959,10 @@ class FabricState:
         if self._cindex is not None and commit.any():
             self._cindex.remove(rin[commit], rout[commit])
         self._pend = {name: pend[name][~commit] for name, _dt in _PEND_FIELDS}
-        if self.scheduling == "reserving":
-            self._tent = None
-            self._tent_valid = None
-        else:
-            self._tent = t_est[~commit]
-            # every surviving row was either spliced from a valid cache
-            # entry or just re-derived by the event loop: all valid
-            self._tent_valid = np.ones(self._tent.size, dtype=bool)
+        self._tent = t_est[~commit]
+        # every surviving row was either spliced from a valid cache entry
+        # or just re-derived by the event loop: all valid
+        self._tent_valid = np.ones(self._tent.size, dtype=bool)
         self.t_now = t_now
         self._ticks += 1
         return out
@@ -2061,7 +1992,6 @@ def cross_check_incremental(
     algorithm: str = "ours",
     *,
     seed: int = 0,
-    scheduling: str = "work-conserving",
     n_ticks: int = 8,
     tick_times: Annotated[F8, "T"] | None = None,
     compare_delta: bool = True,
@@ -2104,14 +2034,13 @@ def cross_check_incremental(
         inst=Instance(coflows=tuple(inst.coflows[int(m)] for m in perm),
                       rates=inst.rates, delta=inst.delta),
         releases=rel[perm])
-    fast = run_fast_online(replay, algorithm, seed=seed, scheduling=scheduling)
+    fast = run_fast_online(replay, algorithm, seed=seed)
 
     st = FabricState(rates=inst.rates, delta=inst.delta, N=inst.N,
-                     algorithm=algorithm, scheduling=scheduling, seed=seed,
-                     delta_schedule=True)
+                     algorithm=algorithm, seed=seed, delta_schedule=True)
     st_full = (FabricState(rates=inst.rates, delta=inst.delta, N=inst.N,
-                           algorithm=algorithm, scheduling=scheduling,
-                           seed=seed, delta_schedule=False)
+                           algorithm=algorithm, seed=seed,
+                           delta_schedule=False)
                if compare_delta else None)
     commits = []
     for T, ids in zip(ticks, batches):
@@ -2141,7 +2070,7 @@ def cross_check_incremental(
         ref[(int(fast.pi[f.coflow]), f.i, f.j)] = (f.core, f.t_establish)
     if set(inc) != set(ref):
         raise AssertionError(
-            f"incremental/replay flow sets differ ({algorithm}, {scheduling}): "
+            f"incremental/replay flow sets differ ({algorithm}): "
             f"{len(inc)} vs {len(ref)} flows")
     for key, (core, te) in inc.items():
         if ref[key] != (core, te):
@@ -2258,7 +2187,6 @@ def cross_check(
     algorithm: str = "ours",
     *,
     seed: int = 0,
-    scheduling: str = "work-conserving",
     atol: float = 1e-6,
     fast: Schedule | None = None,
     backend: str = "numpy",
@@ -2284,42 +2212,30 @@ def cross_check(
     differ from the fp64 oracle's, so the replay isolates the scheduling
     phase under the kernel's precision contract).
     """
-    from functools import partial
-
-    from .circuit_scheduler import (
-        schedule_core_list,
-        schedule_core_reserving,
-        schedule_core_sunflow,
-    )
+    from .circuit_scheduler import schedule_core_list, schedule_core_sunflow
     from .scheduler import _schedule_from_assignment
     from .simulator import validate
 
     if fast is None:
-        fast = run_fast(inst, algorithm, seed=seed, scheduling=scheduling,
-                        backend=backend)
+        fast = run_fast(inst, algorithm, seed=seed, backend=backend)
     pi = order_coflows(inst)
-    policy, sched_eff = _resolve_algorithm(algorithm, scheduling)
+    policy, sunflow = _resolve_algorithm(algorithm)
     flows, choices, oracle_a = _gate_choices(inst, pi, policy, seed, backend)
-    percore = {
-        "work-conserving": schedule_core_list,
-        "priority-guard": partial(schedule_core_list, guard=True),
-        "reserving": schedule_core_reserving,
-        "sunflow": schedule_core_sunflow,
-    }[sched_eff]
+    percore = schedule_core_sunflow if sunflow else schedule_core_list
     if oracle_a is None:  # pallas path: replay the engine's own choices
         oracle_a = assignment_from_choices(inst, pi, flows, choices)
     legacy = _schedule_from_assignment(inst, pi, oracle_a, percore)
     if not np.allclose(fast.ccts, legacy.ccts, atol=atol, rtol=0.0):
         worst = int(np.argmax(np.abs(fast.ccts - legacy.ccts)))
         raise AssertionError(
-            f"engine/oracle CCT mismatch ({algorithm}, {scheduling}): coflow "
+            f"engine/oracle CCT mismatch ({algorithm}): coflow "
             f"{worst}: engine={fast.ccts[worst]!r} oracle={legacy.ccts[worst]!r}")
     key = lambda f: (f.core, f.coflow, f.i, f.j, f.size)
     fast_t = {key(f): f.t_establish for f in fast.flows}
     legacy_t = {key(f): f.t_establish for f in legacy.flows}
     if set(fast_t) != set(legacy_t):
         raise AssertionError(
-            f"engine/oracle flow sets differ ({algorithm}, {scheduling})")
+            f"engine/oracle flow sets differ ({algorithm})")
     for kf, te in fast_t.items():
         if abs(te - legacy_t[kf]) > atol:
             raise AssertionError(
@@ -2334,7 +2250,6 @@ def cross_check_online(
     algorithm: str = "ours",
     *,
     seed: int = 0,
-    scheduling: str = "work-conserving",
     atol: float = 1e-6,
     fast: Schedule | None = None,
     backend: str = "numpy",
@@ -2362,22 +2277,20 @@ def cross_check_online(
     from .simulator import validate
 
     if fast is None:
-        fast = run_fast_online(oinst, algorithm, seed=seed,
-                               scheduling=scheduling, backend=backend)
+        fast = run_fast_online(oinst, algorithm, seed=seed, backend=backend)
     inst = oinst.inst
     rel = np.asarray(oinst.releases, dtype=np.float64)
     arrival, _ = online_orders(inst, rel)
-    policy, _sched_eff = _resolve_algorithm(algorithm, scheduling)
+    policy, _ = _resolve_algorithm(algorithm)
     flows, choices, oracle_a = _gate_choices(inst, arrival, policy, seed,
                                              backend)
     if oracle_a is None:  # pallas path: replay the engine's own choices
         oracle_a = assignment_from_choices(inst, arrival, flows, choices)
-    oracle = run_online(oinst, algorithm, seed=seed, scheduling=scheduling,
-                        assignment=oracle_a)
+    oracle = run_online(oinst, algorithm, seed=seed, assignment=oracle_a)
     if not np.allclose(fast.ccts, oracle.ccts, atol=atol, rtol=0.0):
         worst = int(np.argmax(np.abs(fast.ccts - oracle.ccts)))
         raise AssertionError(
-            f"online engine/oracle CCT mismatch ({algorithm}, {scheduling}): "
+            f"online engine/oracle CCT mismatch ({algorithm}): "
             f"coflow {worst}: engine={fast.ccts[worst]!r} "
             f"oracle={oracle.ccts[worst]!r}")
     key = lambda f: (f.core, f.coflow, f.i, f.j, f.size)
@@ -2385,7 +2298,7 @@ def cross_check_online(
     oracle_t = {key(f): f.t_establish for f in oracle.flows}
     if set(fast_t) != set(oracle_t):
         raise AssertionError(
-            f"online engine/oracle flow sets differ ({algorithm}, {scheduling})")
+            f"online engine/oracle flow sets differ ({algorithm})")
     for kf, te in fast_t.items():
         if abs(te - oracle_t[kf]) > atol:
             raise AssertionError(

@@ -50,11 +50,10 @@ from .circuit_scheduler import (
     ScheduledFlow,
     _run_list_scheduler,
     schedule_core_list,
-    schedule_core_reserving,
 )
 from .coflow import Instance, OnlineInstance
 from .ordering import priority_scores
-from .scheduler import ALGORITHMS, Schedule
+from .scheduler import ALGORITHMS, SUNFLOW_ALGORITHMS, Schedule
 
 __all__ = ["OnlineInstance", "run_online", "online_orders"]
 
@@ -82,8 +81,8 @@ def online_orders(inst: Instance, rel: np.ndarray) -> tuple[np.ndarray, np.ndarr
 
 
 def _assign_at_arrival(inst: Instance, arrival: np.ndarray, algorithm: str,
-                       seed: int) -> tuple[Assignment, str | None]:
-    """Per-arrival irrevocable assignment; returns (assignment, forced policy)."""
+                       seed: int) -> Assignment:
+    """Per-arrival irrevocable assignment."""
     if algorithm in ("ours", "sunflow-core"):
         a = assign_tau_aware(inst, arrival)
     elif algorithm == "rho-assign":
@@ -93,8 +92,7 @@ def _assign_at_arrival(inst: Instance, arrival: np.ndarray, algorithm: str,
     else:
         raise ValueError(
             f"unknown algorithm {algorithm!r}; one of {sorted(ALGORITHMS)}")
-    forced = "sunflow" if algorithm in ("sunflow-core", "rand-sunflow") else None
-    return a, forced
+    return a
 
 
 def run_online(
@@ -102,7 +100,6 @@ def run_online(
     algorithm: str = "ours",
     *,
     seed: int = 0,
-    scheduling: str = "work-conserving",
     assignment: Assignment | None = None,
 ) -> Schedule:
     """Online tau-aware scheduling with arrivals — the reference oracle.
@@ -112,14 +109,11 @@ def run_online(
     Schedule whose feasibility (incl. release-time respect) is validated by
     ``simulator.validate(s, releases=...)``.
 
-    ``scheduling`` selects the intra-core policy (as in ``scheduler.run``):
-    ``work-conserving`` / ``priority-guard`` scan pending *released* flows in
-    WSPT priority order at every event; ``reserving`` commits reservations in
-    arrival order (a reservation cannot be made for a coflow that has not
-    arrived), each no earlier than its release. The sunflow baselines serve
-    one coflow at a time: whenever the core frees, the highest-WSPT-score
-    *arrived* unserved coflow is served next (idling until the next arrival
-    if none is pending).
+    The work-conserving scheduler scans pending *released* flows in WSPT
+    priority order at every event. The sunflow baselines serve one coflow
+    at a time: whenever the core frees, the highest-WSPT-score *arrived*
+    unserved coflow is served next (idling until the next arrival if none is
+    pending).
 
     ``assignment``: replay hook for the differential harness — when given,
     the per-arrival assignment phase is skipped and the provided
@@ -133,12 +127,10 @@ def run_online(
 
     arrival, prio_rank = online_orders(inst, rel)
     if assignment is None:
-        a, forced = _assign_at_arrival(inst, arrival, algorithm, seed)
+        a = _assign_at_arrival(inst, arrival, algorithm, seed)
     else:
         a = assignment
-        forced = ("sunflow" if algorithm in ("sunflow-core", "rand-sunflow")
-                  else None)
-    sched = forced if forced is not None else scheduling
+    sunflow = algorithm in SUNFLOW_ALGORITHMS
     rel_pos = rel[arrival]          # release of the coflow at arrival position
     prio_pos = prio_rank[arrival]   # scheduling priority of that position
 
@@ -146,24 +138,16 @@ def run_online(
     for k in range(inst.K):
         rate = float(inst.rates[k])
         on_k = [af for per in a.flows for af in per if af.core == k]
-        if sched in ("work-conserving", "priority-guard"):
+        if sunflow:
+            all_scheduled.extend(_sunflow_core_online(
+                on_k, k, rate, inst.delta, inst.N, rel_pos, prio_pos))
+        else:
             # WSPT priority scan order: coflow priority rank, then the
             # intra-coflow assignment (largest-first) order.
             on_k.sort(key=lambda af: prio_pos[af.flow.coflow])
             rel_f = np.array([rel_pos[af.flow.coflow] for af in on_k])
             all_scheduled.extend(schedule_core_list(
-                on_k, k, rate, inst.delta, inst.N,
-                guard=(sched == "priority-guard"), releases=rel_f))
-        elif sched == "reserving":
-            # Reservations are committed in arrival order (list order).
-            rel_f = np.array([rel_pos[af.flow.coflow] for af in on_k])
-            all_scheduled.extend(schedule_core_reserving(
                 on_k, k, rate, inst.delta, inst.N, releases=rel_f))
-        elif sched == "sunflow":
-            all_scheduled.extend(_sunflow_core_online(
-                on_k, k, rate, inst.delta, inst.N, rel_pos, prio_pos))
-        else:
-            raise ValueError(f"unknown scheduling {scheduling!r}")
 
     ccts = np.zeros(inst.M)
     for f in all_scheduled:

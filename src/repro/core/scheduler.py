@@ -21,14 +21,13 @@ from .assignment import Assignment, assign_random, assign_rho_only, assign_tau_a
 from .circuit_scheduler import (
     ScheduledFlow,
     schedule_core_list,
-    schedule_core_reserving,
     schedule_core_sunflow,
 )
 from .coflow import Instance
 from .ordering import order_coflows
 
-__all__ = ["FlowColumns", "Schedule", "run", "ALGORITHMS", "weighted_cct",
-           "tail_cct", "tail_quantile"]
+__all__ = ["FlowColumns", "Schedule", "run", "ALGORITHMS",
+           "SUNFLOW_ALGORITHMS", "weighted_cct", "tail_cct", "tail_quantile"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,7 +135,7 @@ def _schedule_from_assignment(
     assignment: Assignment,
     percore: Callable,
 ) -> Schedule:
-    # Split assigned flows by core, preserving global priority order
+    # Split assigned flows by core, keeping global priority order
     # (coflow position in pi, then the intra-coflow assignment order).
     per_core: list[list] = [[] for _ in range(inst.K)]
     for coflow_flows in assignment.flows:
@@ -159,7 +158,6 @@ def run(
     algorithm: str = "ours",
     *,
     seed: int = 0,
-    scheduling: str = "work-conserving",
 ) -> Schedule:
     """Run one of the named algorithms end to end.
 
@@ -169,32 +167,22 @@ def run(
     ``sunflow-core``  : Alg. 1 assignment, Sunflow per-core scheduling
     ``rand-sunflow``  : random assignment + Sunflow per-core scheduling
 
-    ``scheduling`` selects the intra-core policy for the first three:
-    ``work-conserving`` — Alg. 1 lines 23-31 literally: flows scanned in pi
-        order, any flow whose two ports are idle starts (default);
-    ``priority-guard``  — pending higher-priority flows protect their port
-        pairs from lower-priority backfill;
-    ``reserving``       — strict in-order reservation, no backfill.
-    All three are kept for the reproduction sensitivity study (see
-    EXPERIMENTS.md §Reproduction-notes on Lemma 3).
+    The work-conserving scheduler reads Alg. 1 lines 23-31 literally: flows
+    are scanned in pi order, and any flow whose two ports are idle starts.
+    (The two other readings, the guard across coflows and strict in-order
+    reservation, were measured and dropped; see EXPERIMENTS.md
+    §Reproduction-notes on Lemma 3.)
     """
-    from functools import partial
-
-    percore = {
-        "work-conserving": schedule_core_list,
-        "priority-guard": partial(schedule_core_list, guard=True),
-        "reserving": schedule_core_reserving,
-    }[scheduling]
     pi = order_coflows(inst)
     if algorithm == "ours":
         a = assign_tau_aware(inst, pi)
-        return _schedule_from_assignment(inst, pi, a, percore)
+        return _schedule_from_assignment(inst, pi, a, schedule_core_list)
     if algorithm == "rho-assign":
         a = assign_rho_only(inst, pi)
-        return _schedule_from_assignment(inst, pi, a, percore)
+        return _schedule_from_assignment(inst, pi, a, schedule_core_list)
     if algorithm == "rand-assign":
         a = assign_random(inst, pi, seed=seed)
-        return _schedule_from_assignment(inst, pi, a, percore)
+        return _schedule_from_assignment(inst, pi, a, schedule_core_list)
     if algorithm == "sunflow-core":
         a = assign_tau_aware(inst, pi)
         return _schedule_from_assignment(inst, pi, a, schedule_core_sunflow)
@@ -205,6 +193,10 @@ def run(
 
 
 ALGORITHMS = ("ours", "rho-assign", "rand-assign", "sunflow-core", "rand-sunflow")
+
+#: The SUNFLOW-CORE baselines: coflow-at-a-time per-core scheduling in
+#: place of the work-conserving list scheduler.
+SUNFLOW_ALGORITHMS = ("sunflow-core", "rand-sunflow")
 
 
 def weighted_cct(s: Schedule) -> float:

@@ -97,8 +97,9 @@ def check_lemma3(s: Schedule, *, strict: bool = True) -> dict:
     trace workloads at M=50). It DOES hold for single coflows (where the
     proof's charging argument is airtight), and Theorem 1's end-to-end bound
     (which carries a 2*M*psi slack) still holds empirically on every instance
-    we tested. Neither the priority-guarded nor the reserving variant repairs
-    the lemma; both are ~2x worse in weighted CCT. ``strict=False`` returns
+    we tested. Neither a guard across coflows nor strict in-order
+    reservation repaired the lemma when measured (EXPERIMENTS.md
+    §Reproduction-notes), so neither is kept. ``strict=False`` returns
     violations instead of raising.
     """
     inst, pi, a = s.inst, s.pi, _require_assignment(s)

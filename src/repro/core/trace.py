@@ -140,7 +140,7 @@ def sample_instance(
     first-order effect — the regime the paper's defaults (delta=8) target.
 
     ``machine_map="fold"``: alternative reading that maps all 150 racks onto
-    the N ports via random grouping (permutation then mod N), preserving all
+    the N ports via random grouping (permutation then mod N), keeping all
     bytes but densifying every wide coflow.
 
     Receiver-level bytes are split pseudo-uniformly over the coflow's
@@ -218,7 +218,7 @@ def sample_online_instance(
     ``sample_instance`` discards the trace's ``arrival_ms`` column (the
     paper's experiments release everything at t=0); here each picked
     coflow's stamp is mapped affinely onto ``[0, span]`` (instance time
-    units), preserving the trace's relative arrival structure — bursts stay
+    units), keeping the trace's relative arrival structure — bursts stay
     bursts. ``span`` is typically a multiple of the offline makespan, as in
     ``benchmarks/online_arrivals.py``.
     """

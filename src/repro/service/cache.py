@@ -4,7 +4,7 @@ Datacenter traffic is highly repetitive — a training job replays the same
 collective phases every step, so the same demand pattern reaches the fabric
 manager over and over. ``instance_key`` derives a canonical content hash of
 everything the scheduling pipeline reads (demand tensors, weights, rates,
-delta, releases, algorithm/scheduling/seed/backend), and ``ProgramCache`` is
+delta, releases, algorithm/seed/backend), and ``ProgramCache`` is
 a bounded LRU over it: a hit returns the previously compiled
 :class:`~repro.service.program.CircuitProgram` and skips the engine
 entirely. Correctness is cheap to state — the pipeline is a deterministic
@@ -33,7 +33,6 @@ def instance_key(
     releases: Annotated[F8, "M"] | None = None,
     *,
     algorithm: str = "ours",
-    scheduling: str = "work-conserving",
     seed: int = 0,
     backend: str = "numpy",
     fabric: str = "",
@@ -54,7 +53,7 @@ def instance_key(
     degraded) entries.
     """
     h = hashlib.sha256()
-    h.update(f"{algorithm}|{scheduling}|{seed}|{backend}|".encode())
+    h.update(f"{algorithm}|{seed}|{backend}|".encode())
     if fabric:
         h.update(f"fabric={fabric}|".encode())
     h.update(f"M={inst.M},N={inst.N},K={inst.K},delta={inst.delta!r}".encode())

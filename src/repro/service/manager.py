@@ -36,12 +36,7 @@ from repro.core.batch import ResultTable, run_batch
 from repro.core.circuit_scheduler import records_built
 from repro.core.coflow import Coflow, Instance, OnlineInstance
 from repro.core.effects import effects
-from repro.core.engine import (
-    FabricState,
-    INCREMENTAL_SCHEDULINGS,
-    run_fast,
-    run_fast_online,
-)
+from repro.core.engine import FabricState, run_fast, run_fast_online
 from repro.obs.clock import now
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Tracer, compile_count, current_tracer, set_tracer
@@ -76,6 +71,10 @@ class FabricConfig:
     delta: float = 8.0
     N: int = 16
     algorithm: str = "ours"
+    #: The intra-core scheduling, stated rather than chosen: the service
+    #: runs the work-conserving list scheduler, and ``FabricManager``
+    #: refuses any other value. (The sunflow baselines of the one-shot
+    #: plane are chosen by ``algorithm``.)
     scheduling: str = "work-conserving"
     seed: int = 0
     max_queue_depth: int = 1024       # admission backpressure threshold
@@ -162,10 +161,10 @@ class FabricManager:
     def __init__(self, config: FabricConfig = FabricConfig(), *,
                  tracer: Tracer | None = None,
                  metrics: MetricsRegistry | None = None) -> None:
-        if config.scheduling not in INCREMENTAL_SCHEDULINGS:
+        if config.scheduling != "work-conserving":
             raise ValueError(
-                f"service scheduling must be incremental "
-                f"({INCREMENTAL_SCHEDULINGS}), got {config.scheduling!r}")
+                f"the service schedules work-conserving only, got "
+                f"scheduling={config.scheduling!r}")
         self.config = config
         # one shared observability plane: the engine, queue, and cache all
         # record into this manager's tracer + registry
@@ -176,7 +175,7 @@ class FabricManager:
         self.state = FabricState(
             rates=np.asarray(config.rates, dtype=np.float64),
             delta=config.delta, N=config.N, algorithm=config.algorithm,
-            scheduling=config.scheduling, seed=config.seed,
+            seed=config.seed,
             faults=config.faults, track_commits=True,
             delta_schedule=config.delta_schedule,
             fault_lookback=config.fault_lookback,
@@ -422,7 +421,6 @@ class FabricManager:
         inst: Instance | OnlineInstance,
         *,
         algorithm: str | None = None,
-        scheduling: str | None = None,
         seed: int | None = None,
         backend: str = "numpy",
     ) -> tuple[CircuitProgram, bool]:
@@ -445,7 +443,7 @@ class FabricManager:
                 c0 = compile_count() if sp.live else 0
                 r0 = records_built()
                 program, hit = self._schedule_instance(
-                    inst, algorithm, scheduling, seed, backend)
+                    inst, algorithm, seed, backend)
                 materialized = records_built() - r0
                 self._c_materialized.inc(materialized)
                 if sp.live:
@@ -462,13 +460,11 @@ class FabricManager:
         self,
         inst: Instance | OnlineInstance,
         algorithm: str | None,
-        scheduling: str | None,
         seed: int | None,
         backend: str,
     ) -> tuple[CircuitProgram, bool]:
         tracer = self._tracer
         algorithm = self.config.algorithm if algorithm is None else algorithm
-        scheduling = self.config.scheduling if scheduling is None else scheduling
         seed = self.config.seed if seed is None else seed
         releases = None
         if isinstance(inst, OnlineInstance):
@@ -494,8 +490,7 @@ class FabricManager:
                 fp.append("delta_k="
                           + ",".join(repr(float(d)) for d in delta_k))
             fingerprint = ";".join(fp)
-            key = instance_key(inst, releases, algorithm=algorithm,
-                               scheduling=scheduling, seed=seed,
+            key = instance_key(inst, releases, algorithm=algorithm, seed=seed,
                                backend=backend, fabric=fingerprint)
             canonical = self.cache.get(key)
         hit = canonical is not None
@@ -519,14 +514,13 @@ class FabricManager:
                     f"instance has K={inst.K} cores but the drifted fabric "
                     f"has K={self.state.K}; cannot price per-core delays")
             if releases is None:
-                s = run_fast(run_inst, algorithm, seed=seed,
-                             scheduling=scheduling, backend=backend,
+                s = run_fast(run_inst, algorithm, seed=seed, backend=backend,
                              delta_k=run_delta_k)
             else:
                 s = run_fast_online(
                     OnlineInstance(inst=run_inst, releases=releases),
-                    algorithm, seed=seed, scheduling=scheduling,
-                    backend=backend, delta_k=run_delta_k)
+                    algorithm, seed=seed, backend=backend,
+                    delta_k=run_delta_k)
             canonical = compile_schedule(s, index_labels=True)
             if drifted:
                 # stamp each segment's delay in force so emitted programs

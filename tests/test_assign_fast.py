@@ -209,8 +209,7 @@ def test_run_fast_metrics_matches_run_fast():
 def test_run_batch_metrics_mode_matches_full():
     insts = [_random_instance(t) for t in (2, 6)]
     rel = np.random.default_rng(0).exponential(5.0, insts[1].M)
-    kw = dict(seeds=(0, 1), schedulings=("work-conserving", "reserving"),
-              workers=0, releases=(None, rel))
+    kw = dict(seeds=(0, 1), workers=0, releases=(None, rel))
     full = run_batch(insts, ALGORITHMS, check="validate", **kw)
     metrics = run_batch(insts, ALGORITHMS, check="none",
                         materialize="metrics", **kw)

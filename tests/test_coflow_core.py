@@ -251,13 +251,6 @@ class TestCircuitScheduling:
         b_flow = [f for f in s.flows if f.size == 1.0][0]
         assert b_flow.t_establish >= a_done - 1e-9
 
-    def test_reserving_no_backfill(self):
-        A = np.zeros((2, 2)); A[0, 0] = 100.0; A[1, 1] = 50.0
-        B = np.zeros((2, 2)); B[1, 0] = 1.0
-        inst = mk_inst([A, B], rates=(10,), delta=2.0, weights=[10.0, 1.0])
-        s = run(inst, "ours", scheduling="reserving")
-        validate(s)
-
     def test_all_algorithms_feasible(self):
         rng = np.random.default_rng(4)
         demands = [

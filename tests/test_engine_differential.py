@@ -3,11 +3,10 @@
 The engine (``repro.core.engine``) must be *indistinguishable* from the
 legacy per-core reference implementation it replaces: on randomized
 instances spanning N, K, M, delta, demand sparsity, and heterogeneous core
-rates, every algorithm x scheduling-policy combination is driven through
-``cross_check``, which asserts per-coflow CCT agreement (atol 1e-6; the
-engine reproduces the legacy float associativity, so agreement is in fact
-exact), per-flow establishment-time agreement, and independent feasibility
-via ``simulator.validate``.
+rates, every algorithm is driven through ``cross_check``, which asserts
+per-coflow CCT agreement (atol 1e-6; the engine reproduces the legacy float
+associativity, so agreement is in fact exact), per-flow establishment-time
+agreement, and independent feasibility via ``simulator.validate``.
 """
 import numpy as np
 import pytest
@@ -25,7 +24,6 @@ from repro.core import (
 )
 from repro.core.engine import cross_check, schedule_all_cores
 
-LIST_SCHEDULINGS = ("work-conserving", "priority-guard", "reserving")
 N_RANDOM_INSTANCES = 54  # acceptance floor is 50
 
 
@@ -57,12 +55,10 @@ def _random_instance(trial: int) -> Instance:
 
 @pytest.mark.parametrize("trial", range(N_RANDOM_INSTANCES))
 def test_engine_matches_oracle_randomized(trial):
-    """All 5 algorithms x all scheduling policies on one random instance."""
+    """All 5 algorithms on one random instance."""
     inst = _random_instance(trial)
     for alg in ALGORITHMS:
-        scheds = LIST_SCHEDULINGS if "sunflow" not in alg else ("work-conserving",)
-        for sched in scheds:
-            cross_check(inst, alg, seed=trial, scheduling=sched)
+        cross_check(inst, alg, seed=trial)
 
 
 @pytest.mark.slow
@@ -73,21 +69,6 @@ def test_engine_matches_oracle_trace_instance():
                            seed=3)
     for alg in ALGORITHMS:
         cross_check(inst, alg, seed=3)
-    for sched in LIST_SCHEDULINGS:
-        cross_check(inst, "ours", scheduling=sched)
-
-
-def test_engine_scheduling_policies_are_distinct():
-    """Sanity: the engine's policy dispatch isn't aliasing one policy.
-
-    On this fixed instance the work-conserving backfill produces a schedule
-    the guarded variant does not (the repo's reproduction notes show neither
-    direction dominates in general, so only distinctness is asserted).
-    """
-    inst = _random_instance(4)
-    totals = {s: run_fast(inst, "ours", scheduling=s).ccts.sum()
-              for s in LIST_SCHEDULINGS}
-    assert totals["work-conserving"] != totals["priority-guard"]
 
 
 def test_schedule_all_cores_matches_legacy_flow_times():
@@ -99,7 +80,7 @@ def test_schedule_all_cores_matches_legacy_flow_times():
     inst = _random_instance(9)
     pi = order_coflows(inst)
     a = assign_tau_aware(inst, pi)
-    fast = schedule_all_cores(inst, pi, a, "work-conserving")
+    fast = schedule_all_cores(inst, pi, a)
     legacy = _schedule_from_assignment(inst, pi, a, schedule_core_list)
     key = lambda f: (f.core, f.coflow, f.i, f.j)
     fast_by = {key(f): f for f in fast.flows}
@@ -116,11 +97,8 @@ def test_engine_rejects_unknown_inputs():
         run_fast(inst, "nope")
     with pytest.raises(ValueError, match="unknown backend"):
         run_fast(inst, "ours", backend="nope")
-    from repro.core import assign_tau_aware, order_coflows
-    pi = order_coflows(inst)
-    a = assign_tau_aware(inst, pi)
-    with pytest.raises(ValueError, match="unknown scheduling"):
-        schedule_all_cores(inst, pi, a, "nope")
+    with pytest.raises(ValueError, match="delta_k must have shape"):
+        run_fast(inst, "ours", delta_k=np.full(inst.K + 1, inst.delta))
 
 
 def test_run_fast_flat_path_matches_schedule_all_cores():
@@ -133,7 +111,7 @@ def test_run_fast_flat_path_matches_schedule_all_cores():
         inst = _random_instance(trial)
         pi = order_coflows(inst)
         a = assign_tau_aware(inst, pi)
-        via_objects = schedule_all_cores(inst, pi, a, "work-conserving")
+        via_objects = schedule_all_cores(inst, pi, a)
         flat = run_fast(inst, "ours")
         assert flat.assignment is None  # no dataclass materialization
         assert via_objects.assignment is a
@@ -146,17 +124,17 @@ def test_run_fast_flat_path_matches_schedule_all_cores():
 
 def test_run_batch_grid_shape_and_determinism():
     insts = [_random_instance(t) for t in (1, 2)]
-    tab = run_batch(insts, ALGORITHMS, seeds=(0, 1),
-                    schedulings=("work-conserving", "reserving"),
-                    check="validate", workers=0)
-    # 2 insts x 2 seeds x (3 list algs x 2 scheds + 2 sunflow algs x 1)
-    assert len(tab) == 2 * 2 * (3 * 2 + 2)
+    tab = run_batch(insts, ALGORITHMS, seeds=(0, 1), check="validate",
+                    workers=0)
+    # 2 insts x 2 seeds x 5 algorithms
+    assert len(tab) == 2 * 2 * len(ALGORITHMS)
     # sunflow baselines are recorded under their own policy label
     assert {r.scheduling for r in tab.filter(algorithm="sunflow-core")} == {"sunflow"}
+    assert {r.scheduling for r in tab.filter(algorithm="ours")} == {
+        "work-conserving"}
     # deterministic: a repeat run yields identical metrics
-    tab2 = run_batch(insts, ALGORITHMS, seeds=(0, 1),
-                     schedulings=("work-conserving", "reserving"),
-                     check="none", workers=0)
+    tab2 = run_batch(insts, ALGORITHMS, seeds=(0, 1), check="none",
+                     workers=0)
     for a, b in zip(tab, tab2):
         assert a == b or (a.algorithm == b.algorithm and
                           a.weighted_cct == b.weighted_cct)

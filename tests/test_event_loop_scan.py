@@ -1,12 +1,15 @@
-"""The work-conserving event loop against a plain sequential priority scan.
+"""The engine's event loops against plain sequential priority scans.
 
-``_event_loop`` (``guard=False``) picks each event's starts from the freed
+``_event_loop`` (work-conserving) picks each event's starts from the freed
 resources' pending lists. Its times must be bit-identical to the scan it
 stands for: at every event time, walk the pending flows in priority (index)
 order and start each one whose release has come and whose two resources
 are free, then move to the next time a resource frees or a flow is
-released. The reference below does exactly that, in plain Python floats,
-sharing nothing with the engine.
+released. ``_guarded_scan`` (the sunflow baseline's scan) starts, in one
+pass, the flows that are first on both their free resources; it stands
+for the same walk in which a flow that cannot start blocks both its
+resources for the rest of that event. The references below do exactly
+that, in plain Python floats, sharing nothing with the engine.
 """
 from __future__ import annotations
 
@@ -15,7 +18,7 @@ import math
 import numpy as np
 import pytest
 
-from repro.core.engine import LoopCounts, _event_loop
+from repro.core.engine import LoopCounts, _event_loop, _guarded_scan
 
 
 def _sequential(rin, rout, srv, delta, n_res, t0=0.0, release=None,
@@ -47,6 +50,31 @@ def _sequential(rin, rout, srv, delta, n_res, t0=0.0, release=None,
     return np.array(t_est)
 
 
+def _guarded_sequential(rin, rout, srv, delta, n_res, t0=0.0):
+    rin, rout, srv = rin.tolist(), rout.tolist(), srv.tolist()
+    free_in, free_out = [t0] * n_res, [t0] * n_res
+    t_est = [-1.0] * len(rin)
+    pending = list(range(len(rin)))
+    t = t0
+    while pending:
+        blocked_in, blocked_out = set(), set()
+        waiting = []
+        for f in pending:
+            a, b = rin[f], rout[f]
+            if (free_in[a] <= t and free_out[b] <= t
+                    and a not in blocked_in and b not in blocked_out):
+                free_in[a] = free_out[b] = (t + delta) + srv[f]
+                t_est[f] = t
+            else:
+                blocked_in.add(a)
+                blocked_out.add(b)
+                waiting.append(f)
+        pending = waiting
+        if pending:
+            t = min(v for v in free_in + free_out if v > t)
+    return np.array(t_est)
+
+
 def _flows(rng, F, K, N, n_in=None):
     core = rng.integers(0, K, F)
     fi = rng.integers(0, n_in or N, F)
@@ -74,6 +102,10 @@ def _case(kind, seed):
     delta = 8.0
     if kind == "per_flow_delta":
         delta = np.array([8.0, 3.5])[core]
+    if kind == "barrier":
+        # a later start with every resource free then: a sunflow coflow
+        # after the first
+        kw["t0"] = 40.0
     if kind == "release_ties":
         # releases on a coarse grid: many flows released at one time
         kw["release"] = np.round(rng.uniform(0.0, 80.0, F) / 10.0) * 10.0
@@ -102,12 +134,30 @@ KINDS = ["offline", "release_ties", "horizons", "per_flow_delta",
 def test_times_equal_the_sequential_scan(kind, seed):
     (rin, rout, srv, core, delta, n_res, n_ports), kw = _case(kind, seed)
     counts = LoopCounts()
-    got = _event_loop(rin, rout, srv, core, delta, n_res, n_ports,
-                      counts=counts, **kw)
+    got = _event_loop(rin, rout, srv, delta, n_res, counts=counts, **kw)
     ref = _sequential(rin, rout, srv, delta, n_res, **kw)
     assert (got >= kw.get("t0", 0.0)).all()
     assert np.array_equal(got, ref)
     assert counts.events > 0
+
+
+GUARDED_KINDS = ["offline", "simultaneous", "long_lists", "barrier"]
+
+
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("kind", GUARDED_KINDS)
+def test_guarded_times_equal_the_guarded_sequential_scan(kind, seed):
+    (rin, rout, srv, core, delta, n_res, n_ports), kw = _case(kind, seed)
+    counts = LoopCounts()
+    got = _guarded_scan(rin, rout, srv, core, delta, n_res, n_ports,
+                        counts=counts, **kw)
+    ref = _guarded_sequential(rin, rout, srv, delta, n_res, **kw)
+    assert (got >= kw.get("t0", 0.0)).all()
+    assert np.array_equal(got, ref)
+    assert counts.events > 0
+    # the guard matters here: the work-conserving scan starts more
+    assert not np.array_equal(ref, _sequential(rin, rout, srv, delta, n_res,
+                                               **kw))
 
 
 def test_cases_exercise_what_they_name():

@@ -88,15 +88,14 @@ def _ticks_for(oinst, n_ticks):
 
 @pytest.mark.parametrize("seed", range(8))
 def test_zero_event_injector_bit_identical(seed):
-    scheduling = ["work-conserving", "priority-guard", "reserving"][seed % 3]
     algorithm = ["ours", "rho-assign", "rand-assign"][seed % 3]
     oinst = _stream(seed=seed, span=[0.0, 200.0, 500.0][seed % 3])
     ticks = _ticks_for(oinst, 3 + seed % 4)
     plain = FabricState(rates=np.array(RATES), delta=8.0, N=10,
-                        algorithm=algorithm, scheduling=scheduling, seed=seed)
+                        algorithm=algorithm, seed=seed)
     faulty = FabricState(rates=np.array(RATES), delta=8.0, N=10,
-                         algorithm=algorithm, scheduling=scheduling,
-                         seed=seed, faults=FaultInjector([]))
+                         algorithm=algorithm, seed=seed,
+                         faults=FaultInjector([]))
     _assert_commits_equal(_run_ticks(faulty, oinst, ticks),
                           _run_ticks(plain, oinst, ticks))
     assert np.array_equal(faulty.ccts(), plain.ccts())
@@ -124,16 +123,16 @@ def test_core_down_at_zero_equals_k_minus_one(seed, k_fail, algorithm):
     assert np.array_equal(faulted.ccts(), reference.ccts())
 
 
-@pytest.mark.parametrize("scheduling",
-                         ["work-conserving", "priority-guard", "reserving"])
+@pytest.mark.parametrize("scheduling", ["work-conserving"])
 def test_core_down_at_zero_all_schedulings(scheduling):
+    """One case: work-conserving is the one scheduling the incremental
+    path runs."""
     oinst = _stream(seed=11, span=300.0)
     ticks = _ticks_for(oinst, 5)
     faulted = FabricState(
-        rates=np.array(RATES), delta=8.0, N=10, scheduling=scheduling,
+        rates=np.array(RATES), delta=8.0, N=10,
         faults=FaultInjector([CoreDown(t=0.0, core=1)]))
-    reference = FabricState(rates=np.array(RATES)[[0, 2]], delta=8.0, N=10,
-                            scheduling=scheduling)
+    reference = FabricState(rates=np.array(RATES)[[0, 2]], delta=8.0, N=10)
     _assert_commits_equal(_run_ticks(faulted, oinst, ticks),
                           _run_ticks(reference, oinst, ticks),
                           core_map=[0, 2])
@@ -143,23 +142,19 @@ def test_core_down_at_zero_all_schedulings(scheduling):
 def test_fault_differential_fuzz_slow():
     """The long fuzz lane: ~30 more random instances across both anchors."""
     for seed in range(15):
-        scheduling = ["work-conserving", "priority-guard",
-                      "reserving"][seed % 3]
         oinst = _stream(M=20, seed=100 + seed, span=50.0 * (seed % 5))
         ticks = _ticks_for(oinst, 2 + seed % 5)
-        plain = FabricState(rates=np.array(RATES), delta=8.0, N=10,
-                            scheduling=scheduling)
+        plain = FabricState(rates=np.array(RATES), delta=8.0, N=10)
         zero = FabricState(rates=np.array(RATES), delta=8.0, N=10,
-                           scheduling=scheduling, faults=FaultInjector([]))
+                           faults=FaultInjector([]))
         _assert_commits_equal(_run_ticks(zero, oinst, ticks),
                               _run_ticks(plain, oinst, ticks))
         k_fail = seed % K
         up_idx = [k for k in range(K) if k != k_fail]
         down = FabricState(
-            rates=np.array(RATES), delta=8.0, N=10, scheduling=scheduling,
+            rates=np.array(RATES), delta=8.0, N=10,
             faults=FaultInjector([CoreDown(t=0.0, core=k_fail)]))
-        ref = FabricState(rates=np.array(RATES)[up_idx], delta=8.0, N=10,
-                          scheduling=scheduling)
+        ref = FabricState(rates=np.array(RATES)[up_idx], delta=8.0, N=10)
         _assert_commits_equal(_run_ticks(down, oinst, ticks),
                               _run_ticks(ref, oinst, ticks), core_map=up_idx)
 

@@ -140,7 +140,7 @@ def test_kernel_large_f_precision_contract():
     def wcct(choices):
         table = FlowTable(pos=pos, cid=cid, fi=fi, fj=fj, core=choices,
                           size=size)
-        t_est, srv = _times_for_table(inst, pi, table, "work-conserving")
+        t_est, srv = _times_for_table(inst, pi, table)
         return float((inst.weights * _ccts_from_times(inst, pi, table, t_est,
                                                       srv)).sum())
 

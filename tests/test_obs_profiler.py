@@ -23,7 +23,8 @@ import pytest
 from repro.core import sample_instance, synth_fb_trace
 from repro.core.coflow import Instance, OnlineInstance
 from repro.core.engine import (FlowTable, LoopCounts, _event_loop,
-                               _times_for_table, build_flow_table)
+                               _guarded_scan, _times_for_table,
+                               build_flow_table)
 from repro.core.ordering import order_coflows
 from repro.obs import NULL_TRACER, Tracer, current_tracer, set_tracer
 from repro.obs.trace import NULL_SPAN
@@ -212,24 +213,26 @@ def test_programs_bit_identical_profiler_and_tracer(tmp_path, backend):
 # events and candidates against a plain recount
 # ---------------------------------------------------------------------------
 
-def _recount(rin, rout, core, srv, delta, t_est, release=None, guard=False):
-    """Heap pops and candidates of one from-scratch loop (t0 = 0), from the
-    times it produced: the loop pops every heap entry before its last start
-    time T, and one entry equal to T. At each distinct popped time t, under
-    ``guard`` it counts the pending flows of the cores active then; else it
-    scans the pending flows (started at or after t) of each resource freed
-    then, in index order, up to the one that starts on that resource at t,
-    or all of them if none does, and every flow released then."""
+def _recount(rin, rout, core, srv, delta, t_est, release=None, guard=False,
+             t0=0.0):
+    """Heap pops and candidates of one from-scratch loop started at ``t0``,
+    from the times it produced: the loop pops every heap entry before its
+    last start time T, and one entry equal to T. At each distinct popped
+    time t, under ``guard`` (the sunflow scan) it counts the pending flows
+    of the cores with a completion then; else it scans the pending flows
+    (started at or after t) of each resource freed then, in index order, up
+    to the one that starts on that resource at t, or all of them if none
+    does, and every flow released then."""
     tc = (t_est + delta) + srv
     entries = tc.tolist()
     if release is not None:
         entries += np.unique(release).tolist()
     last = float(t_est.max())
-    if last == 0.0:
+    if last == t0:
         return 0, 0
     entries = np.asarray(entries)
     events = int((entries < last).sum()) + 1
-    visited = np.unique(entries[(entries > 0) & (entries <= last)])
+    visited = np.unique(entries[(entries > t0) & (entries <= last)])
     cand = 0
     for t in visited:
         ending = tc == t
@@ -237,9 +240,8 @@ def _recount(rin, rout, core, srv, delta, t_est, release=None, guard=False):
                    else release == t)
         pending = t_est >= t
         if guard:
-            act = set(core[ending].tolist()) | set(
-                core[pending & rel_now].tolist())
-            cand += int((pending & np.isin(core, list(act))).sum())
+            act = np.unique(core[ending])
+            cand += int((pending & np.isin(core, act)).sum())
             continue
         for res in (rin, rout):
             for r in np.unique(res[ending]).tolist():
@@ -262,19 +264,31 @@ def _random_loop_input(seed, F=40, K=2, N=4, online=False):
 
 
 @pytest.mark.parametrize("guard", [False, True])
-@pytest.mark.parametrize("online", [False, True])
+@pytest.mark.parametrize("late", [False, True])
 @pytest.mark.parametrize("seed", range(6))
-def test_loop_counts_equal_a_plain_recount(seed, online, guard):
+def test_loop_counts_equal_a_plain_recount(seed, late, guard):
+    """``guard``: the sunflow baseline's guarded scan in place of the
+    work-conserving loop. ``late``: the work-conserving loop's flows are
+    released over time; the guarded scan, which runs offline, starts at a
+    later barrier instead, as it does for sunflow's coflows after the
+    first."""
     rin, rout, srv, core, n_res, n_ports, release = _random_loop_input(
-        seed, online=online)
+        seed, online=late and not guard)
+    t0 = 30.0 if late and guard else 0.0
+
+    def loop(**kw):
+        if guard:
+            return _guarded_scan(rin, rout, srv, core, 8.0, n_res, n_ports,
+                                 t0=t0, **kw)
+        return _event_loop(rin, rout, srv, 8.0, n_res, release=release, **kw)
+
     counts = LoopCounts()
-    t_est = _event_loop(rin, rout, srv, core, 8.0, n_res, n_ports,
-                        guard=guard, release=release, counts=counts)
-    plain = _event_loop(rin, rout, srv, core, 8.0, n_res, n_ports,
-                        guard=guard, release=release)
-    assert np.array_equal(t_est, plain)  # counting changes no time
+    t_est = loop(counts=counts)
+    assert np.array_equal(t_est, loop())  # counting changes no time
+    assert (t_est >= t0).all()
     assert (counts.events, counts.candidates) == _recount(
-        rin, rout, core, srv, 8.0, t_est, release=release, guard=guard)
+        rin, rout, core, srv, 8.0, t_est, release=release, guard=guard,
+        t0=t0)
 
 
 def test_serial_flows_pop_one_event_a_flow():
@@ -288,8 +302,7 @@ def test_serial_flows_pop_one_event_a_flow():
     rout = np.arange(F, dtype=np.int64) % 4
     srv = np.linspace(1.0, 3.0, F)
     counts = LoopCounts()
-    t_est = _event_loop(rin, rout, srv, np.zeros(F, np.int64), 8.0, 4, 4,
-                        counts=counts)
+    t_est = _event_loop(rin, rout, srv, 8.0, 4, counts=counts)
     assert np.all(np.diff(t_est) > 0)
     assert counts.events == F - 1
     later_on_egress = sum(len(range(f + 4, F, 4)) for f in range(F - 1))
@@ -316,8 +329,8 @@ def test_started_flow_is_never_examined_again(monkeypatch, seed, online):
     rin, rout, srv, core, n_res, n_ports, release = _random_loop_input(
         seed, F=120, N=3, online=online)
     counts = LoopCounts()
-    t_est = _event_loop(rin, rout, srv, core, 8.0, n_res, n_ports,
-                        release=release, counts=counts)
+    t_est = _event_loop(rin, rout, srv, 8.0, n_res, release=release,
+                        counts=counts)
     assert seen and len(seen) <= counts.candidates
     assert all(t_est[f] >= t for t, _lst, f in seen)
     assert len(set(seen)) == len(seen)
@@ -331,8 +344,7 @@ def test_one_shot_event_loop_span_carries_the_counts():
     K, N = inst.K, inst.N
     rin, rout = table.core * N + table.fi, table.core * N + table.fj
     srv = table.size / inst.rates[table.core]
-    _event_loop(rin, rout, srv, table.core, inst.delta, K * N, N,
-                counts=counts)
+    _event_loop(rin, rout, srv, inst.delta, K * N, counts=counts)
     tr = Tracer()
     prev = set_tracer(tr)
     try:
@@ -363,26 +375,25 @@ def test_streaming_event_loop_span_carries_the_counts():
 
 
 def test_reserving_and_sunflow_spans():
+    """The sunflow scheduling's span carries its guarded scans' counts."""
     inst = _instance(M=6, seed=4)
     pi = order_coflows(inst)
     table = build_flow_table(inst, pi)
     tr = Tracer()
     prev = set_tracer(tr)
     try:
-        _times_for_table(inst, pi, table, "reserving")
-        _times_for_table(inst, pi, table, "sunflow")
+        _times_for_table(inst, pi, table, sunflow=True)
     finally:
         set_tracer(prev)
-    res, sun = [r["attrs"] for r in tr.records
-                if r["name"] == "oneshot/event_loop"]
-    assert res == {}
+    sun, = [r["attrs"] for r in tr.records
+            if r["name"] == "oneshot/event_loop"]
     assert sun["events"] > 0 and sun["candidates"] > 0
 
 
 def test_empty_instance_counts_nothing():
     counts = LoopCounts()
     e = np.zeros(0, np.int64)
-    _event_loop(e, e, np.zeros(0), e, 8.0, 4, 4, counts=counts)
+    _event_loop(e, e, np.zeros(0), 8.0, 4, counts=counts)
     assert (counts.events, counts.candidates) == (0, 0)
     table = FlowTable(pos=e, cid=e, fi=e, fj=e, core=e, size=np.zeros(0))
     inst = Instance(coflows=[], rates=np.array(RATES), delta=8.0)

@@ -26,7 +26,6 @@ from repro.core import (
 )
 from repro.core.engine import cross_check_online
 
-LIST_SCHEDULINGS = ("work-conserving", "priority-guard", "reserving")
 N_RANDOM_INSTANCES = 44  # acceptance floor is 40
 ARRIVAL_PATTERNS = ("uniform", "bursty")
 
@@ -73,17 +72,15 @@ def _releases(inst: Instance, pattern: str, trial: int) -> np.ndarray:
 def test_online_engine_matches_oracle_randomized(trial, pattern):
     """Engine == oracle on one random (instance, arrival pattern) point.
 
-    Every point checks the paper algorithm under the policy rotating with
-    the trial, plus a rotating baseline algorithm — over the whole grid all
-    5 algorithms and all list policies are covered many times over.
+    Every point checks the paper algorithm plus a rotating baseline
+    algorithm — over the whole grid all 5 algorithms are covered many times
+    over.
     """
     inst = _random_instance(trial)
     oinst = OnlineInstance(inst=inst, releases=_releases(inst, pattern, trial))
-    cross_check_online(oinst, "ours", seed=trial,
-                       scheduling=LIST_SCHEDULINGS[trial % 3])
+    cross_check_online(oinst, "ours", seed=trial)
     other = [a for a in ALGORITHMS if a != "ours"][trial % 4]
-    cross_check_online(oinst, other, seed=trial,
-                       scheduling=LIST_SCHEDULINGS[(trial // 3) % 3])
+    cross_check_online(oinst, other, seed=trial)
 
 
 @pytest.mark.parametrize("trial", range(0, N_RANDOM_INSTANCES, 7))
@@ -92,12 +89,10 @@ def test_online_zero_releases_match_offline_engine_bitwise(trial):
     inst = _random_instance(trial)
     oinst = OnlineInstance(inst=inst, releases=np.zeros(inst.M))
     for alg in ALGORITHMS:
-        scheds = LIST_SCHEDULINGS if "sunflow" not in alg else ("work-conserving",)
-        for sched in scheds:
-            on = run_fast_online(oinst, alg, seed=trial, scheduling=sched)
-            off = run_fast(inst, alg, seed=trial, scheduling=sched)
-            assert np.array_equal(on.ccts, off.ccts), (alg, sched)
-            assert on.flows == off.flows, (alg, sched)
+        on = run_fast_online(oinst, alg, seed=trial)
+        off = run_fast(inst, alg, seed=trial)
+        assert np.array_equal(on.ccts, off.ccts), alg
+        assert on.flows == off.flows, alg
 
 
 @pytest.mark.slow
@@ -125,10 +120,9 @@ def test_run_batch_online_grid_gating():
     insts = [_random_instance(t) for t in (1, 2)]
     oinsts = [OnlineInstance(inst=i, releases=_releases(i, "uniform", t))
               for t, i in enumerate(insts)]
-    tab = run_batch(oinsts, ALGORITHMS, seeds=(0,),
-                    schedulings=("work-conserving", "reserving"),
-                    check="oracle", workers=0)
-    assert len(tab) == 2 * (3 * 2 + 2)
+    tab = run_batch(oinsts, ALGORITHMS, seeds=(0,), check="oracle",
+                    workers=0)
+    assert len(tab) == 2 * len(ALGORITHMS)
     # rows match a direct engine run
     for idx, oi in enumerate(oinsts):
         row = tab.filter(instance=idx, algorithm="ours",

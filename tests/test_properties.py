@@ -68,14 +68,6 @@ def test_assignment_conserves_demand_exactly(inst):
             atol=1e-9)
 
 
-@settings(max_examples=10, deadline=None)
-@given(instances())
-def test_scheduling_policies_all_feasible(inst):
-    for pol in ("work-conserving", "priority-guard", "reserving"):
-        s = run(inst, "ours", scheduling=pol)
-        validate(s)
-
-
 def test_analyzer_slice_closure():
     """Fusion params reaching dynamic-slice through pass-through ops are
     charged at sliced size (scan-body pattern)."""

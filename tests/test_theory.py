@@ -75,12 +75,6 @@ class TestCertificatesOnTrace:
         res = check_theorem1(trace_schedule)
         assert res["empirical_ratio"] <= res["bound"]
 
-    def test_theorem1_all_policies(self, trace_instance):
-        for pol in ("work-conserving", "priority-guard", "reserving"):
-            s = run(trace_instance, "ours", scheduling=pol)
-            validate(s)
-            check_theorem1(s)
-
     def test_lemma1_all_algorithms(self, trace_instance):
         from repro.core import ALGORITHMS
 
@@ -116,8 +110,8 @@ class TestRandomInstances:
 
 class TestReproductionFindings:
     """Counterexamples found during reproduction — the paper's Lemma 3 proof
-    charges port busy time to prefix traffic only, which neither literal
-    scheduling policy guarantees."""
+    charges port busy time to prefix traffic only, which the literal
+    work-conserving policy does not guarantee."""
 
     def test_lemma3_adversarial_counterexample_work_conserving(self):
         # Coflow 0 (priority): flows (0,0,10) and (1,0,5) — both need egress 0.
@@ -131,22 +125,6 @@ class TestReproductionFindings:
         validate(s)
         res = check_lemma3(s, strict=False)
         assert res["violations"], "expected the documented Lemma 3 violation"
-
-    def test_lemma3_adversarial_counterexample_reserving(self):
-        # Staircase: sequential reservation serializes a chain of flows whose
-        # ports are pairwise entangled, exceeding 2 * per-core LB.
-        N = 8
-        L, s_ = 16.0, 4.0
-        D = np.zeros((N, N))
-        D[0, 0] = L
-        for q in range(1, N):
-            D[q, q - 1] = s_
-            D[q, q] = s_
-        inst = mk_inst([D], rates=(1.0,), delta=0.0)
-        s = run(inst, "ours", scheduling="reserving")
-        validate(s)
-        res = check_lemma3(s, strict=False)
-        assert res["violations"], "expected the documented staircase violation"
 
     def test_theorem2_eq41_deterministic_counterexample(self):
         # Appendix Eq. 41 (ALG <= 2*psi*Gamma_w * sum w*T_LB): with equal
